@@ -236,39 +236,6 @@ def _csv(r):
 MESH_TRIO = [(1, 1), (2, 1), (1, 2)]          # (tp, dp) per row
 
 
-def _mesh_rows():
-    """The mesh trio itself — runs inside the forced-host child."""
-    from repro.configs import get_config, reduced
-    from repro.models import model_init
-
-    cfg = reduced(get_config(ARCH))
-    params = model_init(jax.random.PRNGKey(0), cfg)
-    rows = []
-    for tp, dp in MESH_TRIO:
-        r, _ = _bench_one(cfg, params, "w8a8_nibble", "xla", "mesh",
-                          "paged", alloc_mode="incremental",
-                          prefix_cache=True, shared_prefix=SHARED_PREFIX,
-                          tp=tp, dp=dp)
-        rows.append(r)
-    return rows
-
-
-def _run_mesh_child(rows):
-    """Spawn this file with --mesh-child under a forced 8-device host
-    platform, merge the child's JSON rows, and yield their CSV lines."""
-    import subprocess
-    env = dict(os.environ,
-               XLA_FLAGS="--xla_force_host_platform_device_count=8")
-    out = subprocess.run(
-        [sys.executable, os.path.abspath(__file__), "--mesh-child"],
-        env=env, capture_output=True, text=True, timeout=1800)
-    if out.returncode:
-        raise RuntimeError(f"mesh child failed:\n{out.stderr[-2000:]}")
-    for r in json.loads(out.stdout.strip().splitlines()[-1]):
-        rows.append(r)
-        yield _csv(r)
-
-
 def run(json_path: str | None = None):
     from repro.configs import get_config, reduced
     from repro.models import model_init
@@ -347,11 +314,24 @@ def run(json_path: str | None = None):
     # mesh trio: the same shared-prefix staggered workload as a
     # single-device baseline, TP-sharded (one engine over a (1, 2)
     # mesh), and DP-replicated (two engines behind the router, with
-    # per-replica prefix-affinity hit rates in the JSON row).  Runs in
-    # a child process because the forced-host device count must be set
-    # before jax initializes — the parent already owns a 1-device jax.
-    for line in _run_mesh_child(rows):
-        yield line
+    # per-replica prefix-affinity hit rates in the JSON row).  Runs on
+    # the devices present; a row whose mesh needs more is skipped (on a
+    # CPU host, force devices with
+    # XLA_FLAGS=--xla_force_host_platform_device_count=8)
+    n_dev = len(jax.devices())
+    for tp, dp in MESH_TRIO:
+        if tp > n_dev:
+            yield (f"# skipped mesh row tp={tp} dp={dp}: needs {tp} "
+                   f"devices, {n_dev} present")
+            continue
+        r, warn = _bench_one(cfg, params, "w8a8_nibble", "xla", "mesh",
+                             "paged", alloc_mode="incremental",
+                             prefix_cache=True, shared_prefix=SHARED_PREFIX,
+                             tp=tp, dp=dp)
+        rows.append(r)
+        if warn:
+            yield warn
+        yield _csv(r)
     if json_path:
         payload = {
             "note": "Continuous-batching engine throughput on the reduced "
@@ -426,8 +406,9 @@ def run(json_path: str | None = None):
                     "dp_replicas = engine replicas behind the router "
                     "(1 / [1, 1] / 1 for plain single-device rows). The "
                     "workload=mesh trio re-runs the shared-prefix "
-                    "staggered workload on a forced 8-device host "
-                    "platform: a single-device baseline, tp=2 (weights "
+                    "staggered workload on the devices present (a "
+                    "forced 8-device host platform on CPU): a "
+                    "single-device baseline, tp=2 (weights "
                     "and paged KV pools sharded over the mesh's model "
                     "axis), and dp=2 (two replicas behind the admission "
                     "router — its row carries per_replica placement and "
@@ -452,17 +433,13 @@ def run(json_path: str | None = None):
 
 
 def main():
+    from repro.launch.compile_cache import enable_compile_cache
+
     ap = argparse.ArgumentParser()
     ap.add_argument("--json", default=None,
                     help="also write results to this JSON file")
-    ap.add_argument("--mesh-child", action="store_true",
-                    help="internal: run only the mesh trio and print its "
-                         "rows as JSON (invoked by the parent benchmark "
-                         "under a forced multi-device host platform)")
     args = ap.parse_args()
-    if args.mesh_child:
-        print(json.dumps(_mesh_rows()))
-        return
+    enable_compile_cache()
     for row in run(json_path=args.json):
         print(row)
 

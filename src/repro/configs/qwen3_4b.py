@@ -1,5 +1,5 @@
 """qwen3-4b [dense]: 36L, d_model 2560, 32H GQA kv=8, d_ff 9728,
-vocab 151936 — qk_norm.  [hf:Qwen/Qwen3-8B; hf]"""
+vocab 151936 — qk_norm.  [hf:Qwen/Qwen3-4B config.json]"""
 
 from repro.configs.base import LayerSpec, ModelConfig
 

@@ -110,7 +110,7 @@ def lut_matmul_pallas(x_q: jax.Array, w_q: jax.Array, *,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), jnp.int32),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(x_q, w_q)

@@ -200,7 +200,7 @@ def fused_nibble_matmul_pallas(x_q: jax.Array, w: jax.Array,
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((m, n), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.int32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=_DIM_SEMANTICS),
         interpret=interpret,
     )

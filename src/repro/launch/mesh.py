@@ -16,7 +16,10 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16×16 = 256 chips per pod; 2 pods for the multi-pod config."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    # Auto axes: the sharding rules are with_sharding_constraint hints
+    # that GSPMD propagates, which Explicit axes (the default) reject
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_local_mesh(*, dp: int = 0, tp: int = 1, devices=None):

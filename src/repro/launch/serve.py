@@ -1,4 +1,5 @@
-"""Serving launcher: continuous-batching engine on a reduced config.
+"""Serving launcher: continuous-batching engine on a reduced config, or
+at the architecture's published widths with ``--full-width``.
 
 Batch mode (legacy lockstep generate):
 
@@ -48,6 +49,14 @@ workload:
         --cache-mode paged --alloc-mode incremental --num-pages 24 \
         --prefill-chunk 8 --admit-group 4 --swap-mode host
 
+Published widths (``get_config(arch)`` unreduced, random weights drawn
+from a seed; sized for an accelerator, not for a CPU):
+
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
+        --full-width --workload uniform --requests 16 --batch 8 \
+        --prompt-len 1024 --new-tokens 64 --cache-mode paged \
+        --page-size 16
+
 Compile time is reported separately from steady-state throughput (a
 warmup pass triggers every compilation before the timed run).
 """
@@ -60,6 +69,7 @@ import time
 import jax
 
 from repro.configs import get_config, reduced
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model_init
 from repro.serve import Engine, Router, ServeConfig
 
@@ -76,21 +86,42 @@ def _parse_mesh(spec: str | None):
                          f"got {spec!r}")
 
 
-def _build(args, *, reference: bool = False):
-    """Build the serving stack for ``args``.  ``reference=True`` builds
-    the plain baseline from the same argument set — single-device
-    (tp=1, dp=1, no mesh) AND with every tail-latency mechanism off
-    (monolithic prefill, serialized admission, replay-only resume), so
-    --verify proves chunked/grouped prefill and the host-tier swap
-    against the unmodified engine, not just against themselves."""
+def model_config(args):
+    """The model config ``args`` selects: the published widths with
+    ``--full-width``, else the reduced CPU variant."""
+    cfg = get_config(args.arch)
+    if not args.full_width:
+        cfg = reduced(cfg)
+    return cfg.replace(quant_mode=args.quant)
+
+
+def init_params(cfg, seed: int = 0):
+    """Random weights for ``cfg`` from ``seed``.  Jitted, so only the
+    bf16 leaves materialise: eager init would hold each weight's float32
+    draw (3.6 GB for one stacked qwen3-4b MLP weight) alongside."""
+    return jax.jit(model_init, static_argnums=1)(
+        jax.random.PRNGKey(seed), cfg)
+
+
+def build(args, *, reference: bool = False, params=None):
+    """Build the serving stack for ``args``; returns ``(cfg, params,
+    engine)``.  ``params`` reuses weights already drawn for the same
+    arch and widths (every quant mode serves the one bf16 tree).
+    ``reference=True`` builds the plain baseline from the same argument
+    set — single-device (tp=1, dp=1, no mesh) AND with every
+    tail-latency mechanism off (monolithic prefill, serialized
+    admission, replay-only resume), so --verify proves chunked/grouped
+    prefill and the host-tier swap against the unmodified engine, not
+    just against themselves."""
     tp = 1 if reference else args.tp
     dp = 1 if reference else args.dp
     mesh_shape = None if reference else _parse_mesh(args.mesh)
     prefill_chunk = 0 if reference else args.prefill_chunk
     admit_group = 1 if reference else args.admit_group
     swap_mode = "off" if reference else args.swap_mode
-    cfg = reduced(get_config(args.arch)).replace(quant_mode=args.quant)
-    params = model_init(jax.random.PRNGKey(0), cfg)
+    cfg = model_config(args)
+    if params is None:
+        params = init_params(cfg)
     max_len = args.prompt_len + args.new_tokens
     if args.cache_mode == "paged":
         # the paged pool is page-granular; round the budget up
@@ -149,7 +180,7 @@ def run_batch(args, cfg, engine):
     print("  sample token ids:", out[0, -16:].tolist())
 
 
-def _run_workload(args, cfg, engine, collect_streams=False):
+def run_workload(args, cfg, engine, collect_streams=False):
     from repro.serve import run_timed_workload
     stagger = args.stagger_ms / 1000.0 \
         if args.workload in ("staggered", "bursty") else 0.0
@@ -190,8 +221,8 @@ def _verify(args, cfg, r):
                          "batched activation quantization is batch-"
                          "composition-dependent, so placement changes "
                          "quantized streams")
-    cfg_ref, _, ref = _build(args, reference=True)
-    ref_r = _run_workload(args, cfg_ref, ref, collect_streams=True)
+    cfg_ref, _, ref = build(args, reference=True)
+    ref_r = run_workload(args, cfg_ref, ref, collect_streams=True)
     _check_leaks(args, ref)
     if r["streams"] != ref_r["streams"]:
         bad = [i for i in r["streams"]
@@ -255,7 +286,7 @@ def run_autotune(args):
     if args.workload == "batch":
         raise SystemExit("--autotune plans request workloads "
                          "(uniform/staggered/bursty), not batch mode")
-    cfg = reduced(get_config(args.arch)).replace(quant_mode=args.quant)
+    cfg = model_config(args)
     shape = _workload_shape(args)
     max_len = args.prompt_len + args.new_tokens
     max_len += (-max_len) % args.page_size
@@ -282,7 +313,7 @@ def run_requests(args, cfg, engine):
     ``staggered`` spaces arrivals by --stagger-ms, ``bursty`` clusters
     Poisson bursts at the same mean load with Pareto heavy-tail prompt
     lengths (slots refill mid-stream in all three)."""
-    r = _run_workload(args, cfg, engine, collect_streams=args.verify)
+    r = run_workload(args, cfg, engine, collect_streams=args.verify)
     _check_leaks(args, engine)
     print(f"arch={cfg.name} quant={args.quant} backend={args.quant_backend} "
           f"cache={args.cache_mode} workload={args.workload} "
@@ -343,9 +374,13 @@ def run_requests(args, cfg, engine):
         _verify(args, cfg, r)
 
 
-def main(argv=None):
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-4b")
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve the arch at its published widths "
+                         "(get_config unreduced, random seeded weights); "
+                         "default is the reduced CPU variant")
     ap.add_argument("--batch", type=int, default=4,
                     help="decode slot count")
     ap.add_argument("--prompt-len", type=int, default=32,
@@ -479,14 +514,18 @@ def main(argv=None):
     ap.add_argument("--ttft-slo-ms", type=float, default=None,
                     help="p99 TTFT SLO an --autotune max-tok-s winner "
                          "must meet")
-    args = ap.parse_args(argv)
+    return ap
 
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
     if args.workload == "batch" and args.dp > 1:
         raise SystemExit("--dp applies to request workloads "
                          "(uniform/staggered/bursty), not batch mode")
     if args.autotune:
         return run_autotune(args)
-    cfg, _, engine = _build(args)
+    enable_compile_cache()
+    cfg, _, engine = build(args)
     if args.workload == "batch":
         run_batch(args, cfg, engine)
     else:

@@ -535,10 +535,7 @@ def _flash_self_attention(q, k, v, *, scale, window=0, softcap=0.0):
                             softcap=softcap)
 
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     tp = mesh.shape[shr.TP_AXIS]
     dp = tuple(a for a in mesh.axis_names if a != shr.TP_AXIS)

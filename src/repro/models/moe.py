@@ -145,11 +145,7 @@ def _moe_local(params, cfg, x):
 def _moe_sharded(params, cfg, x, mesh):
     """shard_map EP: tokens on data axes, experts on the model axis."""
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map as _shard_map
-        shard_map = _shard_map
-    except ImportError:  # pragma: no cover
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.distributed.sharding import TP_AXIS
 

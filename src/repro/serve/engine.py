@@ -722,8 +722,17 @@ class Engine:
         # them the compile_counts pins, are untouched)
         self._mesh = self._build_mesh(devices)
         self._caches = init_caches(self.cfg, scfg.batch, scfg.max_len)
+        # where params and caches are committed: the mesh, or — for an
+        # unmeshed engine handed a device — that one device (the router's
+        # one-replica-per-chip placement); None = JAX's default device
+        self._cache_sh = None
         if self._mesh is not None:
             self._shard_state()
+        elif devices is not None:
+            from jax.sharding import SingleDeviceSharding
+            self._cache_sh = SingleDeviceSharding(list(devices)[0])
+            self.params = jax.device_put(self.params, self._cache_sh)
+            self._caches = jax.device_put(self._caches, self._cache_sh)
         # the cache slab/pool is donated: both stages rebind it from the
         # return value, so the update happens in place instead of
         # copying every unmodified row (the out_shardings under a mesh
@@ -830,7 +839,16 @@ class Engine:
     @property
     def device_count(self) -> int:
         """Devices this engine's programs span (1 without a mesh)."""
-        return 1 if self._mesh is None else int(self._mesh.devices.size)
+        return len(self.devices)
+
+    @property
+    def devices(self) -> tuple:
+        """The devices holding this engine's params and caches."""
+        if self._mesh is not None:
+            return tuple(self._mesh.devices.flat)
+        if self._cache_sh is not None:
+            return tuple(self._cache_sh.device_set)
+        return (jax.devices()[0],)
 
     # ------------------------------------------------------------------
     # compiled stages
@@ -1207,7 +1225,7 @@ class Engine:
                                            pad_to=self._swap_pad)
                 self._caches = insert_cache_pages(self._caches, [0], warm,
                                                   pad_to=self._swap_pad)
-                if self._mesh is not None:
+                if self._cache_sh is not None:
                     self._caches = jax.device_put(self._caches,
                                                   self._cache_sh)
             if self.scfg.prefix_cache:
@@ -1398,7 +1416,7 @@ class Engine:
         payloads = [self.host_pool.load(h) for h in hids]
         self._caches = insert_cache_pages(self._caches, pages, payloads,
                                           pad_to=self._swap_pad)
-        if self._mesh is not None:
+        if self._cache_sh is not None:
             # the eager scatter may drop the committed sharding; re-pin
             # before the next donating dispatch sees a layout mismatch
             self._caches = jax.device_put(self._caches, self._cache_sh)
@@ -1808,7 +1826,7 @@ class Engine:
         payloads = [self.host_pool.load(h) for h in req.swap_pages]
         self._caches = insert_cache_pages(self._caches, pages[:n],
                                           payloads, pad_to=self._swap_pad)
-        if self._mesh is not None:
+        if self._cache_sh is not None:
             # the eager scatter may drop the committed sharding; re-pin
             # before the next donating dispatch sees a layout mismatch
             self._caches = jax.device_put(self._caches, self._cache_sh)

@@ -90,17 +90,20 @@ class Router:
     @staticmethod
     def _device_groups(scfg: ServeConfig, replicas: int, devices):
         """Disjoint device slices, one per replica.  Unmeshed engines
-        (tp=1, no mesh_shape) share the default device — N CPU-process
-        replicas on one chip is the functional-testing case; meshed
+        (tp=1, no mesh_shape) get one device each while there are enough
+        devices, and otherwise all share the default device (N replicas
+        on one CPU device is the functional-testing case); meshed
         engines must each get their full complement of devices or the
         fleet cannot be placed at all."""
         shape = scfg.mesh_shape or (1, scfg.tp)
         per = int(shape[0]) * int(shape[1])
-        if per <= 1 and scfg.mesh_shape is None:
-            return [None] * replicas
         if devices is None:
             devices = jax.devices()
         devices = list(devices)
+        if per <= 1 and scfg.mesh_shape is None:
+            if replicas > len(devices):
+                return [None] * replicas
+            return [devices[i:i + 1] for i in range(replicas)]
         if replicas * per > len(devices):
             raise ValueError(
                 f"{replicas} replicas x {per} devices/replica needs "
@@ -308,15 +311,9 @@ class Router:
 
     @property
     def device_count(self) -> int:
-        """Distinct devices the fleet spans (unmeshed replicas share
-        the default device, so N unmeshed CPU replicas report 1)."""
-        devs = set()
-        for eng in self.replicas:
-            if eng._mesh is None:
-                devs.add(jax.devices()[0])
-            else:
-                devs.update(eng._mesh.devices.flat)
-        return len(devs)
+        """Distinct devices the fleet spans (replicas sharing the
+        default device count it once)."""
+        return len({d for eng in self.replicas for d in eng.devices})
 
     def release_prefix_cache(self) -> None:
         for eng in self.replicas:

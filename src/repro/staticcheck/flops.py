@@ -29,7 +29,7 @@ from __future__ import annotations
 import dataclasses
 import math
 
-import jax
+from jax.extend import core as jex_core
 import numpy as np
 
 from repro.core.cycle_model import cycles_per_operand
@@ -133,9 +133,9 @@ def _iter_subjaxprs(eqn):
             continue
         vals = val if isinstance(val, (tuple, list)) else [val]
         for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, jex_core.ClosedJaxpr):
                 yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jex_core.Jaxpr):
                 yield v
     # catch-all for params not in the known list (e.g. custom prims)
     for key, val in eqn.params.items():
@@ -143,9 +143,9 @@ def _iter_subjaxprs(eqn):
             continue
         vals = val if isinstance(val, (tuple, list)) else [val]
         for v in vals:
-            if isinstance(v, jax.core.ClosedJaxpr):
+            if isinstance(v, jex_core.ClosedJaxpr):
                 yield v.jaxpr
-            elif isinstance(v, jax.core.Jaxpr):
+            elif isinstance(v, jex_core.Jaxpr):
                 yield v
 
 
@@ -199,7 +199,7 @@ def _walk(jaxpr, cost: StageCost, mult: int, once_mult: int) -> None:
 
 def walk_jaxpr(closed) -> StageCost:
     """Accumulate static costs over a ``ClosedJaxpr`` (or ``Jaxpr``)."""
-    jaxpr = closed.jaxpr if isinstance(closed, jax.core.ClosedJaxpr) \
+    jaxpr = closed.jaxpr if isinstance(closed, jex_core.ClosedJaxpr) \
         else closed
     cost = StageCost()
     _walk(jaxpr, cost, 1, 1)

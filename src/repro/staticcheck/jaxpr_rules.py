@@ -40,6 +40,7 @@ import math
 import warnings
 
 import jax
+from jax.extend import core as jex_core
 
 from repro.staticcheck.findings import Finding
 from repro.staticcheck.flops import walk_jaxpr, StageCost
@@ -86,9 +87,9 @@ def _jaxprs_with_producers(jaxpr):
             for val in eqn.params.values():
                 vals = val if isinstance(val, (tuple, list)) else [val]
                 for v in vals:
-                    if isinstance(v, jax.core.ClosedJaxpr):
+                    if isinstance(v, jex_core.ClosedJaxpr):
                         stack.append(v.jaxpr)
-                    elif isinstance(v, jax.core.Jaxpr):
+                    elif isinstance(v, jex_core.Jaxpr):
                         stack.append(v)
         yield jx, producers
 
@@ -113,7 +114,7 @@ def _trace_operand(var, producers, depth=24):
 
 def check_quant_widening(jaxpr, path: str, where: str) -> list:
     """SC301 over one (closed) jaxpr."""
-    jx = jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr
+    jx = jaxpr.jaxpr if isinstance(jaxpr, jex_core.ClosedJaxpr) else jaxpr
     findings = []
     for sub, producers in _jaxprs_with_producers(jx):
         for eqn in sub.eqns:
@@ -139,7 +140,7 @@ def check_quant_widening(jaxpr, path: str, where: str) -> list:
 
 def check_callbacks(jaxpr, path: str, where: str) -> list:
     """SC303 over one (closed) jaxpr."""
-    jx = jaxpr.jaxpr if isinstance(jaxpr, jax.core.ClosedJaxpr) else jaxpr
+    jx = jaxpr.jaxpr if isinstance(jaxpr, jex_core.ClosedJaxpr) else jaxpr
     findings = []
     for sub, _producers in _jaxprs_with_producers(jx):
         for eqn in sub.eqns:

@@ -3,11 +3,12 @@ prefix-cache affinity, fleet-level stat aggregation, and bit-match of
 routed greedy streams against the single-engine baseline.
 
 Everything here runs on the one real CPU device — the router's engine
-replicas share it (TP sharding has its own forced-host subprocess test
-in test_mesh_serve.py).  Dense quant throughout the bit-match cases:
-w8a8 activation scales are per-tensor over the batch, so changing which
-requests a replica co-batches (the whole point of placement) would
-legitimately shift quantized streams.
+replicas share it (TP sharding and one-replica-per-device placement
+have their own forced-host subprocess tests in test_mesh_serve.py).
+Dense quant throughout the bit-match cases: w8a8 activation scales are
+per-tensor over the batch, so changing which requests a replica
+co-batches (the whole point of placement) would legitimately shift
+quantized streams.
 """
 
 import jax
@@ -137,7 +138,10 @@ def test_workload_driver_runs_a_router_fleet(model):
                            prompt_budget=8, new_tokens=4,
                            shared_prefix=0.5)
     assert r["dp_replicas"] == 2
-    assert r["device_count"] == 1          # replicas share the one CPU
+    # a replica per device while there are enough devices, else they
+    # share the default one (jax.device_count() is 1 standalone, 512 if
+    # repro.launch.dryrun was imported earlier in the run)
+    assert r["device_count"] == min(2, jax.device_count())
     assert r["mesh_shape"] == [1, 1]
     assert len(r["per_replica"]) == 2
     assert sum(p["placed"] for p in r["per_replica"]) == 6

@@ -83,11 +83,12 @@ def test_mini_dryrun_subprocess():
         from repro.configs import get_config, reduced
         from repro.distributed.sharding import (ambient_mesh, batch_specs,
             opt_state_specs, param_specs)
+        from repro.launch.mesh import make_local_mesh
         from repro.models import model_init
         from repro.optim import AdamWConfig, adamw_init
         from repro.train import TrainConfig, make_train_step
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_local_mesh(dp=4, tp=2)
         cfg = reduced(get_config("qwen3-4b")).replace(
             d_model=64, n_heads=4, n_kv_heads=2, head_dim=16, vocab_size=256)
         tcfg = TrainConfig()
