@@ -240,6 +240,15 @@ def run(json_path: str | None = None):
     from repro.configs import get_config, reduced
     from repro.models import model_init
 
+    # the mesh rows run on the devices present: refuse up front, before
+    # any row is measured, when there are too few of them
+    n_dev = len(jax.devices())
+    need = max(tp * dp for tp, dp in MESH_TRIO)
+    if n_dev < need:
+        raise RuntimeError(
+            f"the mesh rows need {need} devices, {n_dev} present; on a CPU "
+            f"host run with "
+            f"XLA_FLAGS=--xla_force_host_platform_device_count=8")
     cfg = reduced(get_config(ARCH))
     params = model_init(jax.random.PRNGKey(0), cfg)
     yield _HEADER
@@ -314,16 +323,9 @@ def run(json_path: str | None = None):
     # mesh trio: the same shared-prefix staggered workload as a
     # single-device baseline, TP-sharded (one engine over a (1, 2)
     # mesh), and DP-replicated (two engines behind the router, with
-    # per-replica prefix-affinity hit rates in the JSON row).  Runs on
-    # the devices present; a row whose mesh needs more is skipped (on a
-    # CPU host, force devices with
-    # XLA_FLAGS=--xla_force_host_platform_device_count=8)
-    n_dev = len(jax.devices())
+    # per-replica prefix-affinity hit rates in the JSON row), on the
+    # devices present (checked at the top)
     for tp, dp in MESH_TRIO:
-        if tp > n_dev:
-            yield (f"# skipped mesh row tp={tp} dp={dp}: needs {tp} "
-                   f"devices, {n_dev} present")
-            continue
         r, warn = _bench_one(cfg, params, "w8a8_nibble", "xla", "mesh",
                              "paged", alloc_mode="incremental",
                              prefix_cache=True, shared_prefix=SHARED_PREFIX,
@@ -407,7 +409,10 @@ def run(json_path: str | None = None):
                     "(1 / [1, 1] / 1 for plain single-device rows). The "
                     "workload=mesh trio re-runs the shared-prefix "
                     "staggered workload on the devices present (a "
-                    "forced 8-device host platform on CPU): a "
+                    "forced 8-device host platform on CPU, which the "
+                    "whole run then shares: rows measured so are not "
+                    "comparable with rows from a 1-device process, and "
+                    "the dp=2 replicas sit on two devices): a "
                     "single-device baseline, tp=2 (weights "
                     "and paged KV pools sharded over the mesh's model "
                     "axis), and dp=2 (two replicas behind the admission "

@@ -74,10 +74,17 @@ def _tpu_devices(jax, count: int):
     return devices
 
 
-def _peak_gb(device) -> str:
+def _hbm_gb(device, key="peak_bytes_in_use") -> str:
     stats = device.memory_stats() or {}
-    peak = stats.get("peak_bytes_in_use")
+    peak = stats.get(key)
     return "not reported" if peak is None else f"{peak / GB:.2f} GB"
+
+
+def _hbm_per_chip(devices, phase) -> None:
+    """Per chip after ``phase``: HBM in use now, and the peak so far."""
+    print(f"{phase}: HBM in use per chip "
+          f"{[_hbm_gb(d, 'bytes_in_use') for d in devices]}, peak so far "
+          f"{[_hbm_gb(d) for d in devices]}", flush=True)
 
 
 def _serve(serve, argv, params, label):
@@ -176,14 +183,14 @@ def one_chip(serve, jax, jnp, devices) -> None:
                   "tpu_custom_call", flush=True)
         del engine
         gc.collect()  # engines sit in reference cycles: free the KV pool now
-        print(f"{label}: peak HBM so far {_peak_gb(device)}", flush=True)
+        print(f"{label}: peak HBM so far {_hbm_gb(device)}", flush=True)
     a, b = streams["w8a8_nibble/xla"], streams["w8a8_nibble/pallas"]
     same = sum(a[i] == b[i] for i in a)
     check(same == len(a), f"w8a8 pallas vs xla: only {same}/{len(a)} "
                           f"streams bit-identical")
     print(f"check w8a8 pallas == xla: {same}/{len(a)} streams "
           f"bit-identical", flush=True)
-    print(f"peak HBM: {_peak_gb(device)}", flush=True)
+    print(f"peak HBM: {_hbm_gb(device)}", flush=True)
 
 
 def four_chips(serve, jax, jnp, devices) -> None:
@@ -196,6 +203,7 @@ def four_chips(serve, jax, jnp, devices) -> None:
                                             "one chip dense/xla")
     del engine
     gc.collect()  # engines sit in reference cycles: free the KV pool now
+    _hbm_per_chip(devices, "after one chip")
     r, router, params, _, _ = _serve(serve, dense + ["--dp", "4"], params,
                                      "dp=4 dense/xla")
     placed = [eng.devices for eng in router.replicas]
@@ -210,6 +218,7 @@ def four_chips(serve, jax, jnp, devices) -> None:
           flush=True)
     del router
     gc.collect()
+    _hbm_per_chip(devices, "after dp=4")
     r, engine, _, _, _ = _serve(serve, dense + ["--tp", "4"], params,
                                 "tp=4 dense/xla")
     check(engine.mesh_shape == (1, 4) and engine.device_count == 4,
@@ -217,6 +226,7 @@ def four_chips(serve, jax, jnp, devices) -> None:
           f"{engine.device_count} device(s)")
     del engine
     gc.collect()
+    _hbm_per_chip(devices, "after tp=4")
     same, worst = 0, 0.0
     for i, want in ref["streams"].items():
         got = r["streams"][i]
@@ -238,8 +248,7 @@ def four_chips(serve, jax, jnp, devices) -> None:
     print(f"check tp=4: {same}/{len(ref['streams'])} streams bit-identical "
           f"to one chip; partings at near-ties only (largest shortfall "
           f"{worst:.4f} std, tolerance {LOGIT_TOL})", flush=True)
-    print(f"peak HBM per chip: {[_peak_gb(d) for d in devices]}",
-          flush=True)
+    _hbm_per_chip(devices, "after the reference forwards")
 
 
 def main(argv=None) -> int:

@@ -151,16 +151,14 @@ def linear_apply(params: dict, x: jax.Array, *,
             w_format="lut" if mode == "lut" else "int8",
             out_dtype=x.dtype)
 
-    # fence the integer matmul the way the Pallas custom call is fenced:
-    # XLA then fuses the float code around it (norms, the quantizer, the
-    # bf16 rounding of the output) alike for both backends, so their
-    # streams agree bit for bit on TPU as well as on CPU
-    x_v, x_s, w_v, w_s = jax.lax.optimization_barrier(
-        (x_qt.values, x_qt.scale, w_qt.values, w_qt.scale))
     if mode == "lut":
-        acc = lut_matmul_xla(x_v, w_v)
+        acc = lut_matmul_xla(x_qt.values, w_qt.values)
     else:
-        acc = nibble_matmul_xla(x_v, w_v, w_bits=w_bits)
+        acc = nibble_matmul_xla(x_qt.values, w_qt.values, w_bits=w_bits)
 
-    out = acc.astype(jnp.float32) * x_s * w_s.reshape(1, -1)
+    out = acc.astype(jnp.float32) * x_qt.scale * w_qt.scale.reshape(1, -1)
+    # the Pallas kernel rounds its output to x.dtype before it leaves the
+    # custom call; without this fence XLA fuses the f32 product into its
+    # consumer and skips that rounding on TPU, which parts the two
+    # backends' greedy streams
     return jax.lax.optimization_barrier(out.astype(x.dtype))

@@ -721,16 +721,22 @@ class Engine:
         # built without sharding kwargs, so its signatures, and with
         # them the compile_counts pins, are untouched)
         self._mesh = self._build_mesh(devices)
-        self._caches = init_caches(self.cfg, scfg.batch, scfg.max_len)
-        # where params and caches are committed: the mesh, or — for an
-        # unmeshed engine handed a device — that one device (the router's
-        # one-replica-per-chip placement); None = JAX's default device
+        # an unmeshed engine handed a device (the router's round-robin
+        # placement) builds its pool there: a pool built on the default
+        # device and then moved holds that device's memory until the copy
+        # lands, for every replica placed elsewhere
+        device = (list(devices)[0] if self._mesh is None
+                  and devices is not None else None)
+        with jax.default_device(device):
+            self._caches = init_caches(self.cfg, scfg.batch, scfg.max_len)
+        # where params and caches are committed: the mesh, that one
+        # device, or None = JAX's default device
         self._cache_sh = None
         if self._mesh is not None:
             self._shard_state()
-        elif devices is not None:
+        elif device is not None:
             from jax.sharding import SingleDeviceSharding
-            self._cache_sh = SingleDeviceSharding(list(devices)[0])
+            self._cache_sh = SingleDeviceSharding(device)
             self.params = jax.device_put(self.params, self._cache_sh)
             self._caches = jax.device_put(self._caches, self._cache_sh)
         # the cache slab/pool is donated: both stages rebind it from the

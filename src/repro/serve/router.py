@@ -89,21 +89,19 @@ class Router:
 
     @staticmethod
     def _device_groups(scfg: ServeConfig, replicas: int, devices):
-        """Disjoint device slices, one per replica.  Unmeshed engines
-        (tp=1, no mesh_shape) get one device each while there are enough
-        devices, and otherwise all share the default device (N replicas
-        on one CPU device is the functional-testing case); meshed
-        engines must each get their full complement of devices or the
-        fleet cannot be placed at all."""
+        """Device slices, one per replica.  Unmeshed engines (tp=1, no
+        mesh_shape) take one device each, round-robin: more replicas
+        than devices share them (N replicas on one CPU device is the
+        functional-testing case) and replicas on one device share its
+        weight buffer.  Meshed engines must each get their own full
+        complement of devices or the fleet cannot be placed at all."""
         shape = scfg.mesh_shape or (1, scfg.tp)
         per = int(shape[0]) * int(shape[1])
         if devices is None:
             devices = jax.devices()
         devices = list(devices)
         if per <= 1 and scfg.mesh_shape is None:
-            if replicas > len(devices):
-                return [None] * replicas
-            return [devices[i:i + 1] for i in range(replicas)]
+            return [[devices[i % len(devices)]] for i in range(replicas)]
         if replicas * per > len(devices):
             raise ValueError(
                 f"{replicas} replicas x {per} devices/replica needs "

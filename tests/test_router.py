@@ -113,6 +113,18 @@ def test_router_prefix_affinity_follows_cached_pages(model):
     assert router.leaked_pages() == 0
 
 
+@pytest.mark.parametrize("replicas,n_dev,want", [
+    (1, 4, [0]), (4, 4, [0, 1, 2, 3]), (3, 2, [0, 1, 0]),
+    (8, 4, [0, 1, 2, 3, 0, 1, 2, 3]), (2, 1, [0, 0])])
+def test_unmeshed_replicas_take_devices_round_robin(replicas, n_dev, want):
+    """tp=1 replicas get one device each, round-robin over the devices
+    given: more replicas than devices spread over all of them instead
+    of piling onto the first."""
+    devices = [f"dev{i}" for i in range(n_dev)]
+    groups = Router._device_groups(_scfg(), replicas, devices)
+    assert groups == [[devices[i]] for i in want]
+
+
 def test_router_rejects_bad_sizing(model):
     cfg, params = model
     with pytest.raises(ValueError, match="replicas must be >= 1"):
@@ -138,9 +150,9 @@ def test_workload_driver_runs_a_router_fleet(model):
                            prompt_budget=8, new_tokens=4,
                            shared_prefix=0.5)
     assert r["dp_replicas"] == 2
-    # a replica per device while there are enough devices, else they
-    # share the default one (jax.device_count() is 1 standalone, 512 if
-    # repro.launch.dryrun was imported earlier in the run)
+    # replicas take devices round-robin, so two replicas span two devices
+    # where there are two, else share the one (jax.device_count() is 1
+    # standalone, 512 if repro.launch.dryrun was imported earlier)
     assert r["device_count"] == min(2, jax.device_count())
     assert r["mesh_shape"] == [1, 1]
     assert len(r["per_replica"]) == 2
