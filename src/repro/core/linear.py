@@ -135,30 +135,37 @@ def linear_apply(params: dict, x: jax.Array, *,
         return jnp.dot(xq, wq).astype(x.dtype)
 
     # integer serving modes -------------------------------------------------
+    # (the named scopes mark each cost in the operations' metadata, so a
+    # device trace can put time on it)
     w_bits = 4 if mode == "w4a8_nibble" else 8
-    x_f = x.astype(jnp.float32)
-    x_qt = q.quantize(x_f, bits=8, granularity="per_tensor")
-    w_qt = q.quantize(w.astype(jnp.float32), bits=w_bits,
-                      granularity="per_channel", axis=0)
+    with jax.named_scope("quant.act"):
+        x_qt = q.quantize(x.astype(jnp.float32), bits=8,
+                          granularity="per_tensor")
+    with jax.named_scope("quant.weight"):
+        w_qt = q.quantize(w.astype(jnp.float32), bits=w_bits,
+                          granularity="per_channel", axis=0)
 
-    if backend == "pallas":
-        from repro.kernels import ops  # deferred: kernels import pallas
-        # single dispatch path; nibble modes fuse the dequant epilogue
-        # in-kernel and emit x.dtype directly (no int32 HBM round-trip)
-        return ops.quant_matmul(
-            x_qt.values, w_qt.values,
-            x_scale=x_qt.scale, w_scale=w_qt.scale.reshape(1, -1),
-            w_format="lut" if mode == "lut" else "int8",
-            out_dtype=x.dtype)
+    with jax.named_scope("nibble.matmul"):
+        if backend == "pallas":
+            from repro.kernels import ops  # deferred: kernels import pallas
+            # single dispatch path; nibble modes fuse the dequant epilogue
+            # in-kernel and emit x.dtype directly (no int32 HBM round-trip)
+            return ops.quant_matmul(
+                x_qt.values, w_qt.values,
+                x_scale=x_qt.scale, w_scale=w_qt.scale.reshape(1, -1),
+                w_format="lut" if mode == "lut" else "int8",
+                out_dtype=x.dtype)
 
-    if mode == "lut":
-        acc = lut_matmul_xla(x_qt.values, w_qt.values)
-    else:
-        acc = nibble_matmul_xla(x_qt.values, w_qt.values, w_bits=w_bits)
+        if mode == "lut":
+            acc = lut_matmul_xla(x_qt.values, w_qt.values)
+        else:
+            acc = nibble_matmul_xla(x_qt.values, w_qt.values,
+                                    w_bits=w_bits)
 
-    out = acc.astype(jnp.float32) * x_qt.scale * w_qt.scale.reshape(1, -1)
-    # the Pallas kernel rounds its output to x.dtype before it leaves the
-    # custom call; without this fence XLA fuses the f32 product into its
-    # consumer and skips that rounding on TPU, which parts the two
-    # backends' greedy streams
-    return jax.lax.optimization_barrier(out.astype(x.dtype))
+        out = (acc.astype(jnp.float32) * x_qt.scale
+               * w_qt.scale.reshape(1, -1))
+        # the Pallas kernel rounds its output to x.dtype before it leaves
+        # the custom call; without this fence XLA fuses the f32 product
+        # into its consumer and skips that rounding on TPU, which parts
+        # the two backends' greedy streams
+        return jax.lax.optimization_barrier(out.astype(x.dtype))

@@ -330,6 +330,9 @@ def run_requests(args, cfg, engine):
           f"p99={r['ttft_p99_ms']:.0f}ms   "
           f"itl p50={r['itl_p50_ms']:.1f}ms p99={r['itl_p99_ms']:.1f}ms")
     print(f"  cache HBM/request: {r['cache_kb_per_req']:.1f} KiB")
+    print(f"  scheduler: queue wait {r['queue_wait_s']:.3f}s summed over "
+          f"fresh placements, {r['prefill_rows']} prefill rows "
+          f"dispatched, {r['decode_rows']} decode rows")
     if args.spec_decode:
         print(f"  spec decode: k={args.spec_k} "
               f"draft={args.spec_quant or args.quant} "

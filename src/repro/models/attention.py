@@ -26,6 +26,12 @@ __all__ = ["attn_init", "attn_apply", "mla_init", "mla_apply",
 
 _NEG_INF = -2.0 ** 30
 
+# named scopes of the KV cache's two costs in a paged decode step, so a
+# device trace can put time on each: writing the new rows, and gathering
+# the pages back into position order
+CACHE_WRITE = "attn.cache_write"
+GATHER = "attn.gather"
+
 # When True, the query-chunk loop is a Python loop (static unroll) so the
 # compiled HLO contains every chunk — used by the dry-run's cost pass,
 # because XLA's cost_analysis counts a while body once regardless of trip
@@ -71,20 +77,21 @@ def scatter_cache_rows(buf, new, index):
     """
     new = new.astype(buf.dtype)
     index = jnp.asarray(index)
-    if index.ndim == 0:
-        start = (0, index) + (0,) * (buf.ndim - 2)
-        return jax.lax.dynamic_update_slice(buf, new, start)
-    if new.shape[1] == 1:
-        def one(b, n, i):
-            return jax.lax.dynamic_update_slice(
-                b, n, (i,) + (0,) * (b.ndim - 1))
+    with jax.named_scope(CACHE_WRITE):
+        if index.ndim == 0:
+            start = (0, index) + (0,) * (buf.ndim - 2)
+            return jax.lax.dynamic_update_slice(buf, new, start)
+        if new.shape[1] == 1:
+            def one(b, n, i):
+                return jax.lax.dynamic_update_slice(
+                    b, n, (i,) + (0,) * (b.ndim - 1))
 
-        return jax.vmap(one)(buf, new, index)
-    b, s = new.shape[:2]
-    pos = jnp.clip(index[:, None].astype(jnp.int32)
-                   + jnp.arange(s, dtype=jnp.int32)[None, :],
-                   0, buf.shape[1] - 1)
-    return buf.at[jnp.arange(b)[:, None], pos].set(new)
+            return jax.vmap(one)(buf, new, index)
+        b, s = new.shape[:2]
+        pos = jnp.clip(index[:, None].astype(jnp.int32)
+                       + jnp.arange(s, dtype=jnp.int32)[None, :],
+                       0, buf.shape[1] - 1)
+        return buf.at[jnp.arange(b)[:, None], pos].set(new)
 
 
 # ---------------------------------------------------------------------------
@@ -116,17 +123,21 @@ def scatter_paged_rows(pool, new, table, index):
     or the slot's final row, which no query attends before its owner's
     final write rewrites it (the idempotent-write invariant).
     """
-    ps = pool.shape[1]
-    b, s = new.shape[:2]
-    index = jnp.broadcast_to(jnp.asarray(index, jnp.int32).reshape(-1), (b,))
-    if s == 1:
-        page = jnp.take_along_axis(table, (index // ps)[:, None],
-                                   axis=1)[:, 0]
-        return pool.at[page, index % ps].set(new[:, 0].astype(pool.dtype))
-    pos = jnp.clip(index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :],
-                   0, table.shape[1] * ps - 1)
-    page = jnp.take_along_axis(table, pos // ps, axis=1)
-    return pool.at[page, pos % ps].set(new.astype(pool.dtype))
+    with jax.named_scope(CACHE_WRITE):
+        ps = pool.shape[1]
+        b, s = new.shape[:2]
+        index = jnp.broadcast_to(
+            jnp.asarray(index, jnp.int32).reshape(-1), (b,))
+        if s == 1:
+            page = jnp.take_along_axis(table, (index // ps)[:, None],
+                                       axis=1)[:, 0]
+            return pool.at[page, index % ps].set(
+                new[:, 0].astype(pool.dtype))
+        pos = jnp.clip(
+            index[:, None] + jnp.arange(s, dtype=jnp.int32)[None, :],
+            0, table.shape[1] * ps - 1)
+        page = jnp.take_along_axis(table, pos // ps, axis=1)
+        return pool.at[page, pos % ps].set(new.astype(pool.dtype))
 
 
 def gather_pages(pool, table):
@@ -140,8 +151,9 @@ def gather_pages(pool, table):
     ``-inf``).
     """
     b, mp = table.shape
-    g = jnp.take(pool, table, axis=0)           # (B, MP, page_size, ...)
-    return g.reshape(b, mp * pool.shape[1], *pool.shape[2:])
+    with jax.named_scope(GATHER):
+        g = jnp.take(pool, table, axis=0)       # (B, MP, page_size, ...)
+        return g.reshape(b, mp * pool.shape[1], *pool.shape[2:])
 
 
 def scatter_prefill_rows(pool, new, row, start):
@@ -159,10 +171,11 @@ def scatter_prefill_rows(pool, new, row, start):
     """
     ps = pool.shape[1]
     s = new.shape[1]
-    pos = jnp.clip(jnp.asarray(start, jnp.int32) + jnp.arange(s), 0,
-                   row.shape[0] * ps - 1)
-    page = jnp.take(row, pos // ps)
-    return pool.at[page, pos % ps].set(new[0].astype(pool.dtype))
+    with jax.named_scope(CACHE_WRITE):
+        pos = jnp.clip(jnp.asarray(start, jnp.int32) + jnp.arange(s), 0,
+                       row.shape[0] * ps - 1)
+        page = jnp.take(row, pos // ps)
+        return pool.at[page, pos % ps].set(new[0].astype(pool.dtype))
 
 
 def _splice_context(ctx, new, context_start):

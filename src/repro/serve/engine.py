@@ -159,6 +159,7 @@ dry-run cells lower.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
 import time
 from typing import Callable
@@ -186,6 +187,21 @@ from repro.serve.paging import (
     PageTable,
     PrefixCache,
     pages_needed,
+)
+from repro.serve.spans import (
+    ADMIT,
+    DECODE,
+    EMIT,
+    EVICT,
+    IDLE,
+    PREFILL,
+    READBACK,
+    RESUME,
+    STEP,
+    UPLOAD,
+    WAIT,
+    span,
+    step_span,
 )
 
 __all__ = ["ServeConfig", "Request", "make_serve_step", "Engine"]
@@ -423,6 +439,29 @@ class _PriorityQueue:
         return req
 
 
+# stage -> the name of its jitted function.  JAX compiles a function
+# ``f`` as the program ``jit_f``, and the benchmark's readers match those
+# program names (``jit_chunk``, ``jit_prefill_into_slot``), so the names
+# come from this one table and not from the name of the closure a
+# ``_build_*`` method returns.
+STAGE_FUNCTIONS = {"prefill": "prefill_into_slot", "prefill_chunk": "wave",
+                   "decode_chunk": "chunk", "draft": "draft",
+                   "verify": "verify"}
+
+
+def _stage_body(fn, stage: str):
+    """``fn`` under its stage's function name, its body inside
+    ``jax.named_scope(stage)`` so every operation's metadata names the
+    stage it ran in."""
+    @functools.wraps(fn)
+    def body(*args):
+        with jax.named_scope(stage):
+            return fn(*args)
+
+    body.__name__ = body.__qualname__ = STAGE_FUNCTIONS[stage]
+    return body
+
+
 class _CountingJit:
     """Engine-owned compile counter around ``jax.jit``.
 
@@ -438,9 +477,15 @@ class _CountingJit:
     ``ShapeDtypeStruct`` leaves that can be fed to ``jit_fn.lower`` /
     ``jit_fn.trace`` long after the run, so ``repro.staticcheck`` can
     re-lower every stage program a live engine actually compiled and
-    inspect the jaxpr/HLO without re-running the workload."""
+    inspect the jaxpr/HLO without re-running the workload.
 
-    def __init__(self, fn, **jit_kwargs):
+    ``stage`` names an engine stage (a key of ``STAGE_FUNCTIONS``): the
+    program is then compiled under the table's name and scoped by the
+    stage."""
+
+    def __init__(self, fn, stage: str | None = None, **jit_kwargs):
+        if stage is not None:
+            fn = _stage_body(fn, stage)
         self._fn = jax.jit(fn, **jit_kwargs)
         self._keys: set = set()
 
@@ -746,28 +791,28 @@ class Engine:
         if self._wave:
             self._prefill_fn = None
             self._wave_fn = _CountingJit(self._build_wave_prefill(),
-                                         donate_argnums=1,
+                                         "prefill_chunk", donate_argnums=1,
                                          **self._stage_shardings(9, 2))
         else:
             self._wave_fn = None
             n_pre = 10 if scfg.prefix_cache else 7
             self._prefill_fn = _CountingJit(
-                self._build_prefill(), donate_argnums=1,
+                self._build_prefill(), "prefill", donate_argnums=1,
                 **self._stage_shardings(n_pre, 2))
         if self._spec:
             # exactly two decode-side programs — one quantized draft,
             # one dense verify; _chunk_fn is never built or called, so
             # its pinned compile count is 0 (see ``compile_counts``)
             self._chunk_fn = None
-            self._draft_fn = _CountingJit(self._build_draft(),
+            self._draft_fn = _CountingJit(self._build_draft(), "draft",
                                           donate_argnums=1,
                                           **self._stage_shardings(10, 3))
-            self._verify_fn = _CountingJit(self._build_verify(),
+            self._verify_fn = _CountingJit(self._build_verify(), "verify",
                                            donate_argnums=1,
                                            **self._stage_shardings(10, 3))
         else:
             self._chunk_fn = _CountingJit(self._build_decode_chunk(),
-                                          donate_argnums=1,
+                                          "decode_chunk", donate_argnums=1,
                                           **self._stage_shardings(11, 7))
         self._next_id = 0
         self.reset()
@@ -1198,6 +1243,11 @@ class Engine:
         # replay (= generated rows restored by page copy)
         self.prefill_waves = 0
         self.decode_chunks = 0
+        # scheduler counters at the boundaries the spans mark (``stats``)
+        self.prefill_rows = 0
+        self.decode_rows = 0
+        self.queue_wait_s = 0.0
+        self._step_num = 0
         self.swap_outs = 0
         self.swap_ins = 0
         self.replay_steps_saved = 0
@@ -1343,6 +1393,14 @@ class Engine:
                 # pages (both 0 with the mechanisms off)
                 "prefill_waves": self.prefill_waves,
                 "decode_chunks": self.decode_chunks,
+                # scheduler counters (see ``spans``): prefill_rows =
+                # rows the prefill program dispatched, padding included;
+                # decode_rows = active slot-steps decoded; queue_wait_s
+                # = summed wait of fresh requests from due time to
+                # placement
+                "prefill_rows": self.prefill_rows,
+                "decode_rows": self.decode_rows,
+                "queue_wait_s": self.queue_wait_s,
                 "swap_out": self.swap_outs,
                 "swap_in": self.swap_ins,
                 "replay_steps_saved": self.replay_steps_saved,
@@ -1353,6 +1411,10 @@ class Engine:
                                       else 0),
                 "prefix_cold_hits": self.prefix_cold_hits,
                 "prefix_demotions": self.prefix_demotions}
+
+    def running(self) -> list[Request]:
+        """The requests in slots: decoding, or (wave mode) prefilling."""
+        return [r for r in self._slots if r is not None]
 
     @property
     def cache_token_bytes(self) -> int:
@@ -1576,6 +1638,10 @@ class Engine:
         requeue the request carrying every token generated so far (the
         replay lane restores them on re-admission)."""
         req = self._slots[slot]
+        with span(EVICT, req=req.id):
+            self._evict_slot(slot, req)
+
+    def _evict_slot(self, slot: int, req: Request) -> None:
         if self._slot_forced[slot]:
             # evicted mid-replay: splice the unreplayed tail back so the
             # requeued request carries the full generated stream
@@ -1652,6 +1718,10 @@ class Engine:
         """Admit arrived requests into free slots, best effective
         priority first; a strictly-higher-priority arrival blocked on a
         slot or on pages preempts the weakest runner(s)."""
+        with span(ADMIT, queued=len(self._queue)):
+            self._admit_arrived(now)
+
+    def _admit_arrived(self, now: float) -> None:
         while True:
             free = next((s for s in range(self.scfg.batch)
                          if self._slots[s] is None), None)
@@ -1689,7 +1759,7 @@ class Engine:
                     return
             self._place(free, req, now)
 
-    def _prefix_place(self, slot: int, req: Request, rng):
+    def _prefix_place(self, slot: int, req: Request, key):
         """Prefix-cache admission: map the cached prefix read-only, book
         only the remaining pages, and run the uncached suffix through
         the shared compiled prefill (a miss is simply ``start == 0``).
@@ -1724,19 +1794,32 @@ class Engine:
         # [start, p_len), so without a fixed slot budget the buffer
         # pads to the FULL prompt length, not the suffix length (which
         # would roll the fresh keys off the end of a short buffer)
-        pad_len = self.scfg.prefill_len or p_len
+        pad_len = self._prefill_pad(p_len)
         padded = np.zeros((1, pad_len), np.int32)
         padded[0, :sfx_len] = sfx
-        self._caches, first = self._prefill_fn(
-            self.params, self._caches, jnp.asarray(padded), sfx_len,
-            slot, jnp.asarray(self.page_table.row(slot)), start,
-            cow_src, cow_dst, rng)
+        with span(UPLOAD):
+            args = (jnp.asarray(padded), sfx_len, slot,
+                    jnp.asarray(self.page_table.row(slot)), start,
+                    cow_src, cow_dst,
+                    jax.random.fold_in(jnp.asarray(key), 0))
+        self._caches, first = self._prefill_fn(self.params, self._caches,
+                                               *args)
         self._prefix_insert(keys, pages)
         self.prefill_tokens += sfx_len
+        self.prefill_rows += pad_len
         self._cached_prompt_tokens += start
         self._prefix_hits += bool(shared or n_cold or cow_src)
         self.cow_copies += bool(cow_src)
         return first
+
+    def _prefill_pad(self, p_len: int) -> int:
+        """Rows the prefill program runs for a prompt of ``p_len``
+        tokens: the slot budget, or the prompt itself where there is
+        none (and always for mamba mixers, which end-padding would
+        corrupt)."""
+        if self._has_mamba or not self.scfg.prefill_len:
+            return p_len
+        return self.scfg.prefill_len
 
     def _place(self, slot: int, req: Request, now: float) -> None:
         """Prefill a request into a free slot.  Fresh requests sample
@@ -1744,7 +1827,32 @@ class Engine:
         (non-empty ``tokens``) reuse their stored first token and queue
         the rest on the slot's teacher-forcing lane, so the rebuilt KV
         — and, for greedy decode, every later token — bit-matches an
-        uninterrupted run."""
+        uninterrupted run.  A fresh request's wait from its due time to
+        here counts in ``queue_wait_s``."""
+        if req.tokens:
+            # a replay re-prefills the prompt; a swap-in, or a wave
+            # lane, dispatches no prefill here
+            rows = (0 if req.swap_pages is not None or self._wave
+                    else self._prefill_pad(int(req.prompt.size)))
+            with span(RESUME, req=req.id, rows=rows):
+                self._place_in_slot(slot, req)
+            return
+        wait_s = time.perf_counter() - self._t0 - req.arrival
+        # a wave lane evicted mid-prefill comes back with no tokens: its
+        # wait was counted at its first placement
+        if not req.preemptions:
+            self.queue_wait_s += wait_s
+        if self._wave:
+            # pages only: the waves prefill it, each an engine.prefill
+            self._place_in_slot(slot, req)
+            return
+        p_len = int(req.prompt.size)
+        with span(PREFILL, req=req.id, tokens=p_len,
+                  rows=self._prefill_pad(p_len),
+                  queue_wait_ms=1e3 * wait_s):
+            self._place_in_slot(slot, req)
+
+    def _place_in_slot(self, slot: int, req: Request) -> None:
         p_len = int(req.prompt.size)
         resumed = bool(req.tokens)
         # index-derived stream key: the same request always gets the
@@ -1768,14 +1876,10 @@ class Engine:
             # scheduler step through the shared wave program
             self._wave_admit(slot, req)
             return
-        sub = jax.random.fold_in(jnp.asarray(key), 0)
         if self.prefix_cache is not None:
-            first = self._prefix_place(slot, req, sub)
+            first = self._prefix_place(slot, req, key)
         else:
-            if self._has_mamba or not self.scfg.prefill_len:
-                pad_len = p_len          # exact-length prefill
-            else:
-                pad_len = self.scfg.prefill_len
+            pad_len = self._prefill_pad(p_len)
             if self._paged:
                 # tokens stay at pad_len (page-rounding them would feed
                 # extra pad tokens through mamba mixers); the prefill
@@ -1790,29 +1894,39 @@ class Engine:
             padded = np.zeros((1, pad_len), np.int32)
             padded[0, :p_len] = req.prompt
             self.prefill_tokens += p_len
+            self.prefill_rows += pad_len
+            with span(UPLOAD):
+                args = (jnp.asarray(padded), p_len, slot,
+                        jnp.asarray(self.page_table.row(slot)),
+                        jax.random.fold_in(jnp.asarray(key), 0))
             self._caches, first = self._prefill_fn(
-                self.params, self._caches, jnp.asarray(padded), p_len,
-                slot, jnp.asarray(self.page_table.row(slot)), sub)
-        if resumed:
-            tok = req.tokens[0]
-            self._slot_forced[slot] = req.tokens[1:]
-            req.tokens = [tok]
-        else:
-            self._slot_forced[slot] = []
-            tok = int(first)
-            req.tokens.append(tok)
-            req.t_first = time.perf_counter() - self._t0
-            req.t_tokens.append(req.t_first)
-        done = (req.max_new_tokens <= 1
-                or (self.scfg.eos_id >= 0 and tok == self.scfg.eos_id))
-        if done:
-            self._finish(req, slot)
-        else:
-            self._slots[slot] = req
-            self._token[slot, 0] = tok
-            self._positions[slot] = p_len
-            self._active[slot] = True
-            self._remaining[slot] = req.max_new_tokens - 1
+                self.params, self._caches, *args)
+        if not resumed:
+            with span(WAIT):
+                jax.block_until_ready(first)
+            with span(READBACK):
+                tok = int(first)
+        with span(EMIT):
+            if resumed:
+                tok = req.tokens[0]
+                self._slot_forced[slot] = req.tokens[1:]
+                req.tokens = [tok]
+            else:
+                self._slot_forced[slot] = []
+                req.tokens.append(tok)
+                req.t_first = time.perf_counter() - self._t0
+                req.t_tokens.append(req.t_first)
+            done = (req.max_new_tokens <= 1
+                    or (self.scfg.eos_id >= 0
+                        and tok == self.scfg.eos_id))
+            if done:
+                self._finish(req, slot)
+            else:
+                self._slots[slot] = req
+                self._token[slot, 0] = tok
+                self._positions[slot] = p_len
+                self._active[slot] = True
+                self._remaining[slot] = req.max_new_tokens - 1
 
     def _swap_in(self, slot: int, req: Request) -> None:
         """Resume a swapped-out request by page copy: restore its live
@@ -1914,6 +2028,14 @@ class Engine:
         G, C = self._wave_group, self._wave_chunk
         lanes = [s for s in range(self.scfg.batch)
                  if self._prefill_next[s] >= 0][:G]
+        real = [min(C, int(self._slots[s].prompt.size)
+                    - int(self._prefill_next[s])) for s in lanes]
+        with span(PREFILL, lanes=len(lanes), tokens=sum(real),
+                  rows=G * C):
+            self._wave_dispatch(lanes, real)
+
+    def _wave_dispatch(self, lanes: list, real: list) -> None:
+        G, C = self._wave_group, self._wave_chunk
         tokens = np.zeros((G, C), np.int32)
         lens = np.ones((G,), np.int32)
         starts = np.zeros((G,), np.int32)
@@ -1921,12 +2043,11 @@ class Engine:
         cow_src = np.zeros((G,), np.int32)
         cow_dst = np.zeros((G,), np.int32)
         keys = np.zeros((G, 2), np.uint32)
-        real = []
         for i, s in enumerate(lanes):
             req = self._slots[s]
             st = int(self._prefill_next[s])
             p_len = int(req.prompt.size)
-            n = min(C, p_len - st)
+            n = real[i]
             tokens[i, :n] = req.prompt[st:st + n]
             lens[i] = n
             starts[i] = st
@@ -1938,22 +2059,26 @@ class Engine:
                 cow_src[i], cow_dst[i] = cs, cd
                 self.cow_copies += 1
             keys[i] = self._slot_keys[s]
-            real.append(n)
             self.prefill_tokens += n
         self.prefill_waves += 1
-        self._caches, first = self._wave_fn(
-            self.params, self._caches, jnp.asarray(tokens),
-            jnp.asarray(lens), jnp.asarray(starts), jnp.asarray(rows),
-            jnp.asarray(cow_src), jnp.asarray(cow_dst),
-            jnp.asarray(keys))
-        first = np.asarray(first)
-        for i, s in enumerate(lanes):
-            req = self._slots[s]
-            nxt = int(self._prefill_next[s]) + real[i]
-            if nxt >= int(req.prompt.size):
-                self._wave_finish(s, req, int(first[i]))
-            else:
-                self._prefill_next[s] = nxt
+        self.prefill_rows += G * C
+        with span(UPLOAD):
+            args = tuple(map(jnp.asarray, (tokens, lens, starts, rows,
+                                           cow_src, cow_dst, keys)))
+        self._caches, first = self._wave_fn(self.params, self._caches,
+                                            *args)
+        with span(WAIT):
+            jax.block_until_ready(first)
+        with span(READBACK):
+            first = np.asarray(first)
+        with span(EMIT):
+            for i, s in enumerate(lanes):
+                req = self._slots[s]
+                nxt = int(self._prefill_next[s]) + real[i]
+                if nxt >= int(req.prompt.size):
+                    self._wave_finish(s, req, int(first[i]))
+                else:
+                    self._prefill_next[s] = nxt
 
     def _wave_finish(self, slot: int, req: Request, first: int) -> None:
         """A lane's final chunk ran: index the prompt's pages (prefix
@@ -2050,8 +2175,15 @@ class Engine:
             self._top_up(now)
             if not self._active.any():
                 return               # top-up evicted the last runner
-        b = self.scfg.batch
         nsteps = self.scfg.decode_chunk
+        with span(DECODE, active=int(self._active.sum()),
+                  steps=nsteps) as s:
+            s.set_metadata(rows=self._decode_chunk(nsteps))
+
+    def _decode_chunk(self, nsteps: int) -> int:
+        """Dispatch one decode chunk, read it back and emit its tokens;
+        returns the active slot-steps it ran."""
+        b = self.scfg.batch
         forced = np.full((nsteps, b), -1, np.int32)
         forced_on = np.zeros((nsteps, b), bool)
         for slot in range(b):
@@ -2069,37 +2201,45 @@ class Engine:
         counts = np.asarray(
             [len(r.tokens) if r is not None else 0 for r in self._slots],
             np.int32)
-        (self._caches, token, positions, active, remaining,
-         toks, valid) = self._chunk_fn(
-            self.params, self._caches, jnp.asarray(self._token),
-            jnp.asarray(self._positions), jnp.asarray(self._active),
-            jnp.asarray(self._remaining),
-            jnp.asarray(self.page_table.asarray()),
-            jnp.asarray(forced), jnp.asarray(forced_on),
-            jnp.asarray(self._slot_keys), jnp.asarray(counts))
-        self._token = np.array(token)        # copies: host state is mutable
-        self._positions = np.array(positions)
-        self._active = np.array(active)
-        self._remaining = np.array(remaining)
-        toks, valid = np.asarray(toks), np.asarray(valid)
-        tnow = time.perf_counter() - self._t0
-        for slot, req in enumerate(self._slots):
-            if req is None:
-                continue
-            for t in range(toks.shape[0]):
-                if not valid[t, slot]:
-                    break
-                tok = int(toks[t, slot])
-                req.tokens.append(tok)
-                if len(req.t_tokens) < len(req.tokens):
-                    # replayed tokens keep their original stamps
-                    req.t_tokens.append(tnow)
-                if (len(req.tokens) >= req.max_new_tokens
-                        or (self.scfg.eos_id >= 0
-                            and tok == self.scfg.eos_id)):
-                    self._finish(req, slot)
-                    self._slots[slot] = None
-                    break
+        with span(UPLOAD):
+            args = tuple(map(jnp.asarray, (
+                self._token, self._positions, self._active,
+                self._remaining, self.page_table.asarray(), forced,
+                forced_on, self._slot_keys, counts)))
+        self._caches, *outs = self._chunk_fn(self.params, self._caches,
+                                             *args)
+        with span(WAIT):
+            jax.block_until_ready(outs)
+        with span(READBACK):
+            # copies: host state is mutable
+            token, positions, active, remaining, toks, valid = outs
+            self._token = np.array(token)
+            self._positions = np.array(positions)
+            self._active = np.array(active)
+            self._remaining = np.array(remaining)
+            toks, valid = np.asarray(toks), np.asarray(valid)
+        rows = int(valid.sum())
+        self.decode_rows += rows
+        with span(EMIT):
+            tnow = time.perf_counter() - self._t0
+            for slot, req in enumerate(self._slots):
+                if req is None:
+                    continue
+                for t in range(toks.shape[0]):
+                    if not valid[t, slot]:
+                        break
+                    tok = int(toks[t, slot])
+                    req.tokens.append(tok)
+                    if len(req.t_tokens) < len(req.tokens):
+                        # replayed tokens keep their original stamps
+                        req.t_tokens.append(tnow)
+                    if (len(req.tokens) >= req.max_new_tokens
+                            or (self.scfg.eos_id >= 0
+                                and tok == self.scfg.eos_id)):
+                        self._finish(req, slot)
+                        self._slots[slot] = None
+                        break
+        return rows
 
     def _spec_rollback(self, slot: int) -> None:
         """Roll a slot's rejected draft tail back as a page-table
@@ -2136,8 +2276,14 @@ class Engine:
             self._top_up(now)
             if not self._active.any():
                 return               # top-up evicted the last runner
-        b = self.scfg.batch
         k = self.scfg.spec_k
+        active = int(self._active.sum())
+        with span(DECODE, active=active, steps=k, rows=active * k):
+            self._spec_round(k)
+        self.decode_rows += active * k
+
+    def _spec_round(self, k: int) -> None:
+        b = self.scfg.batch
         forced = np.full((k, b), -1, np.int32)
         forced_on = np.zeros((k, b), bool)
         for slot in range(b):
@@ -2155,23 +2301,35 @@ class Engine:
             [len(r.tokens) if r is not None else 0 for r in self._slots],
             np.int32)
         start = self._positions.copy()
-        keys = jnp.asarray(self._slot_keys)
-        counts_j = jnp.asarray(counts)
-        table = jnp.asarray(self.page_table.asarray())
-        f_on = jnp.asarray(forced_on)
-        token = jnp.asarray(self._token)
-        start_j = jnp.asarray(start)
+        with span(UPLOAD):
+            keys = jnp.asarray(self._slot_keys)
+            counts_j = jnp.asarray(counts)
+            table = jnp.asarray(self.page_table.asarray())
+            f_on = jnp.asarray(forced_on)
+            token = jnp.asarray(self._token)
+            start_j = jnp.asarray(start)
+            active_j = jnp.asarray(self._active)
+            forced_j = jnp.asarray(forced)
         # draft → verify stay device-side: the drafted tokens and their
         # distributions flow straight into the verify dispatch
         self._caches, drafts, dprobs = self._draft_fn(
-            self.params, self._caches, token, start_j,
-            jnp.asarray(self._active), table, jnp.asarray(forced), f_on,
-            keys, counts_j)
+            self.params, self._caches, token, start_j, active_j, table,
+            forced_j, f_on, keys, counts_j)
         self._caches, out, accept = self._verify_fn(
             self.params, self._caches, token, drafts, start_j, table,
             f_on, dprobs, keys, counts_j)
-        out = np.asarray(out)            # (B, k+1) emission candidates
-        accept = np.asarray(accept)      # (B, k) per-draft verdicts
+        with span(WAIT):
+            jax.block_until_ready((out, accept))
+        with span(READBACK):
+            out = np.asarray(out)        # (B, k+1) emission candidates
+            accept = np.asarray(accept)  # (B, k) per-draft verdicts
+        with span(EMIT):
+            self._spec_emit(k, start, forced_on, out, accept)
+
+    def _spec_emit(self, k: int, start, forced_on, out, accept) -> None:
+        """The emission walk of one speculation round (see
+        ``_run_spec_round``)."""
+        b = self.scfg.batch
         self.spec_rounds += 1
         tnow = time.perf_counter() - self._t0
         eos = self.scfg.eos_id
@@ -2236,6 +2394,11 @@ class Engine:
         if not (len(self._queue)
                 or any(r is not None for r in self._slots)):
             return False
+        self._step_num += 1
+        with step_span(STEP, step_num=self._step_num):
+            return self._step(wait)
+
+    def _step(self, wait: bool) -> bool:
         now = time.perf_counter() - self._t0
         self._admit(now)
         # wave mode: slots mid-prefill are inactive but NOT idle — they
@@ -2249,7 +2412,8 @@ class Engine:
             wait_s = nxt - (time.perf_counter() - self._t0)
             if wait_s > 0:             # idle until the next arrival
                 if wait:
-                    time.sleep(min(wait_s, 0.05))
+                    with span(IDLE):
+                        time.sleep(min(wait_s, 0.05))
                 return True
             if nxt > now:
                 # the request arrived *during* this iteration's _admit

@@ -130,7 +130,7 @@ class Router:
 
     def _load(self, eng: Engine) -> int:
         """JSQ key: queued plus running requests on a replica."""
-        return len(eng._queue) + sum(r is not None for r in eng._slots)
+        return len(eng._queue) + len(eng.running())
 
     def _pick_replica(self, req: Request) -> int:
         """Prefix affinity first (most cached chunks of this prompt),
@@ -274,6 +274,9 @@ class Router:
                                        for s in per),
             "prefill_waves": sum(s["prefill_waves"] for s in per),
             "decode_chunks": sum(s["decode_chunks"] for s in per),
+            "prefill_rows": sum(s["prefill_rows"] for s in per),
+            "decode_rows": sum(s["decode_rows"] for s in per),
+            "queue_wait_s": sum(s["queue_wait_s"] for s in per),
             "swap_out": sum(s["swap_out"] for s in per),
             "swap_in": sum(s["swap_in"] for s in per),
             "replay_steps_saved": sum(s["replay_steps_saved"]

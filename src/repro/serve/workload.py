@@ -41,7 +41,12 @@ definitions cannot drift apart:
   ``concurrency`` (mean admitted requests per chunk — what overcommit
   buys from the same pool), plus ``truncated`` (requests whose
   ``max_new_tokens`` was clamped to the ``max_len`` budget at submit —
-  flagged explicitly so a short stream is never misread as early EOS).
+  flagged explicitly so a short stream is never misread as early EOS),
+  and ``prefill_rows`` (rows the prefill program dispatched, padding
+  included), ``decode_rows`` (active slot-steps decoded) and
+  ``queue_wait_s`` (fresh requests' summed wait from due time to
+  placement), counted where the engine's profiler spans mark the same
+  boundaries (``repro.serve.spans``).
 """
 
 from __future__ import annotations
@@ -298,6 +303,9 @@ def run_timed_workload(engine, vocab_size: int, *, requests: int,
         "swap_in": stats.get("swap_in", 0),
         "replay_steps_saved": stats.get("replay_steps_saved", 0),
         "prefix_cold_hits": stats.get("prefix_cold_hits", 0),
+        "prefill_rows": stats["prefill_rows"],
+        "decode_rows": stats["decode_rows"],
+        "queue_wait_s": round(stats["queue_wait_s"], 3),
         "truncated": int(sum(done[i].truncated for i in ids)),
         "compile_s": round(compile_s, 2),
         "compile_counts": engine.compile_counts,

@@ -16,8 +16,10 @@ Rule catalog (see docs/staticcheck.md):
   static and not flagged).
 * **SC105** — ``jax.device_get`` / ``.block_until_ready()`` inside the
   engine step paths (``src/repro/serve``): the engine's host boundary
-  is ``np.asarray`` on stage outputs, by design exactly once per
-  dispatch; ad-hoc syncs hide dispatch stalls.
+  is one named wait per dispatch (``block_until_ready`` inside a
+  ``with span(WAIT)`` block, the trace's ``engine.wait``) followed by
+  ``np.asarray`` on the stage's outputs; ad-hoc syncs hide dispatch
+  stalls.
 * **SC201** — a cache-carrying jit site (the wrapped function has a
   ``caches``-like parameter) that does not pass ``donate_argnums``
   covering it: the pool is then copied every dispatch.
@@ -315,10 +317,29 @@ def _lint_jitted_body(fn: ast.FunctionDef, static_names: set,
     return findings
 
 
+def _named_waits(tree: ast.Module) -> set:
+    """The calls inside a ``with span(WAIT)`` block: a stage's one wait
+    on its outputs, which the trace names ``engine.wait`` (see
+    ``repro.serve.spans``), right before the host copies them."""
+    calls = set()
+    for node in ast.walk(tree):
+        if isinstance(node, ast.With) and any(
+                isinstance(item.context_expr, ast.Call)
+                and item.context_expr.args
+                and _dotted(item.context_expr.args[0]).split(".")[-1]
+                == "WAIT" for item in node.items):
+            calls |= {id(sub) for stmt in node.body
+                      for sub in ast.walk(stmt)
+                      if isinstance(sub, ast.Call)}
+    return calls
+
+
 def _lint_serve_host_sync(tree: ast.Module, relpath: str) -> list:
     """SC105 over a serve/ module (whole file, not just jitted
-    bodies)."""
+    bodies).  A ``block_until_ready`` inside a ``with span(WAIT)``
+    block is the stage's named wait, not an ad-hoc sync."""
     findings = []
+    waits = _named_waits(tree)
     for node in ast.walk(tree):
         if isinstance(node, ast.Call):
             if _dotted(node.func) in ("jax.device_get", "device_get"):
@@ -328,7 +349,8 @@ def _lint_serve_host_sync(tree: ast.Module, relpath: str) -> list:
                     "engine's host boundary is np.asarray on stage "
                     "outputs", node.lineno))
             elif (isinstance(node.func, ast.Attribute)
-                  and node.func.attr == "block_until_ready"):
+                  and node.func.attr == "block_until_ready"
+                  and id(node) not in waits):
                 findings.append(Finding(
                     "SC105", relpath, "module",
                     ".block_until_ready() in an engine step path "

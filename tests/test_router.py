@@ -158,8 +158,32 @@ def test_workload_driver_runs_a_router_fleet(model):
     assert len(r["per_replica"]) == 2
     assert sum(p["placed"] for p in r["per_replica"]) == 6
     assert r["compile_counts"] == {"prefill": 1, "decode_chunk": 1}
+    # the engines' scheduler counters, summed over the fleet: every
+    # placement dispatched the slot budget's rows, every token after the
+    # first was decoded
+    assert r["prefill_rows"] >= 6 * 8
+    assert r["decode_rows"] >= 6 * (4 - 1)
+    assert r["queue_wait_s"] >= 0.0
     router.release_prefix_cache()
     assert router.leaked_pages() == 0
+
+
+def test_jsq_load_counts_the_running_requests(model):
+    """A replica's load is its queue plus ``Engine.running()``: the
+    requests placed in its slots."""
+    cfg, params = model
+    router = Router(cfg, params, _scfg(), replicas=2)
+    for p in _prompts(cfg.vocab_size, n=3, seed=2):
+        router.submit(p, 8)          # more than the first step decodes
+    router.start()
+    router.step()
+    running = [eng.running() for eng in router.replicas]
+    assert sorted(map(len, running)) == [1, 2]
+    assert all(r.tokens for rs in running for r in rs)
+    assert [router._load(eng) for eng in router.replicas] == \
+        [len(rs) for rs in running]
+    router.run()
+    assert not any(eng.running() for eng in router.replicas)
 
 
 def test_workload_priority_split_survives_empty_class(model):

@@ -151,6 +151,47 @@ def test_sc105_host_sync_in_serve(tmp_path):
     assert _lint(tmp_path / "other", bad, "src/repro/launch/x.py") == []
 
 
+def test_sc105_allows_only_the_named_wait(tmp_path):
+    """A stage's one wait, inside ``with span(WAIT)``, is the engine's
+    host boundary; the same call anywhere else, or a ``device_get``
+    inside the wait, is still an ad-hoc sync."""
+    named = """
+        import jax
+        import numpy as np
+        from repro.serve.spans import WAIT, span
+        def step(x):
+            with span(WAIT):
+                jax.block_until_ready(x)
+            return np.asarray(x)
+    """
+    bare = """
+        import jax
+        def step(x):
+            jax.block_until_ready(x)
+            return x
+    """
+    other_span = """
+        import jax
+        from repro.serve.spans import UPLOAD, span
+        def step(x):
+            with span(UPLOAD):
+                jax.block_until_ready(x)
+            return x
+    """
+    get_inside = """
+        import jax
+        from repro.serve.spans import WAIT, span
+        def step(x):
+            with span(WAIT):
+                return jax.device_get(x)
+    """
+    rel = "src/repro/serve/stepper.py"
+    assert _lint(tmp_path / "named", named, rel) == []
+    assert "SC105" in _lint(tmp_path / "bare", bare, rel)
+    assert "SC105" in _lint(tmp_path / "other", other_span, rel)
+    assert "SC105" in _lint(tmp_path / "get", get_inside, rel)
+
+
 def test_sc201_cache_jit_must_donate(tmp_path):
     bad = """
         import jax
