@@ -50,10 +50,12 @@ The packed-int4 weight variant unpacks two nibbles per byte in-kernel
 multiplier), halving HBM→VMEM weight traffic, then runs the identical
 plane-concatenated dot.
 
-Tiling: grid ``(M/bm, N/bn, K/bk)``.  Block defaults are MXU-aligned
-(multiples of 128 in every matmul dimension; int8 native lane tiling is
-(32, 128), which 128-multiples satisfy).  The concatenated contraction
-width ``2·bk`` remains a multiple of 128.
+Tiling: grid ``(M/bm, N/bn, K/bk)``.  ``ops.quant_matmul`` picks the
+blocks from each call's shape (``ops.pick_blocks``): ``bm`` a multiple of
+32 (int8 native tiling is (32, 128)), ``bn`` and ``bk`` multiples of 128,
+as wide as VMEM allows, since on the chip each grid step costs a fixed
+overhead.  The concatenated contraction width ``2·bk`` remains a multiple
+of 128.
 """
 
 from __future__ import annotations

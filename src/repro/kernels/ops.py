@@ -64,11 +64,97 @@ def _col_scale(s, n):
     return jnp.broadcast_to(s, (1, n))
 
 
+# ---------------------------------------------------------------------------
+# Block choice: each nibble-kernel call's blocks come from its own shape
+# ---------------------------------------------------------------------------
+
+# On the chip the kernel's cost is mostly a fixed overhead per grid step
+# (about 0.2-0.3 us on a v5e, whatever the block), so a call wants as few
+# steps as VMEM allows.  Blocks never pad a dimension further than the
+# 128-multiple it is padded to anyway.
+_WIDE = (512, 256, 128)
+_SUBLANES_I8 = 32            # int8 sublane tiling: the row block's unit
+_SMALL_M = 512               # up to here M is one row block
+VMEM_LIMIT = 16 * 2**20      # the default scoped VMEM of a Mosaic kernel
+
+_TILE_PLAN: dict = {}
+
+
+def _round_up(x: int, mult: int) -> int:
+    return -(-x // mult) * mult
+
+
+def _widest(dim: int) -> int:
+    """The largest of ``_WIDE`` that divides ``dim`` rounded up to 128."""
+    padded = _round_up(dim, 128)
+    return next(b for b in _WIDE if padded % b == 0)
+
+
+def vmem_bytes(bm: int, bn: int, bk: int, *, out_bytes: int = 4,
+               scaled: bool = True) -> int:
+    """VMEM the nibble kernel holds for one set of blocks: double-buffered
+    x, w, scale and out blocks, the int32 accumulator, the in-kernel int32
+    planes (x, lo, hi<<4 and the dot's result) and the ``(bm, 2·bk)`` /
+    ``(2·bk, bn)`` plane concatenations (int32 before the int8 cast).
+
+    An upper bound: the compiler fuses part of this (compiling for a v5e
+    needed 0.3-0.8x the estimate at qwen3-4b's projection shapes)."""
+    io = bm * bk + bk * bn + bm * bn * out_bytes
+    if scaled:     # (bm, 1) pads to 128 lanes, (1, bn) to 8 sublanes
+        io += 4 * (bm * 128 + 8 * bn)
+    planes = 4 * (3 * bm * bk + bm * bn)
+    concat = 4 * bm * 2 * bk + bm * 2 * bk + 2 * bk * bn
+    return 2 * io + 4 * bm * bn + planes + concat
+
+
+def pick_blocks(m: int, k: int, n: int, w_format: str = "int8", *,
+                out_bytes: int = 4, scaled: bool = True
+                ) -> tuple[int, int, int]:
+    """``(bm, bn, bk)`` for one call, from its shape and format alone.
+
+    ``bm``: M rounded up to 32 while M <= 512 (a decode dispatch is one
+    row block), else the largest of 512/256/128 dividing M rounded up to
+    128.  ``bn``: that rule on N.  ``bk``: that rule on K; a call of at
+    most 512 rows instead takes the widest 128-multiple dividing K rounded
+    up to 128, the whole K where it fits (it streams its weights, and each
+    K step costs as much as a small dot).  Where the VMEM estimate passes
+    the scoped limit, ``bk`` steps down first, then ``bn``.  The "lut"
+    and "int4_packed" formats keep 128³ blocks (the packed format's
+    in-kernel unpack spills its registers at 512-wide blocks).
+    """
+    if w_format != "int8":
+        return 128, 128, 128
+    small = m <= _SMALL_M
+    bm = _round_up(max(m, 1), _SUBLANES_I8) if small else _widest(m)
+    bn = _widest(n)
+    kp = _round_up(k, 128)
+    bks = [b for b in (range(kp, 0, -128) if small else _WIDE)
+           if kp % b == 0]
+
+    def fits(bn_, bk_):
+        return vmem_bytes(bm, bn_, bk_, out_bytes=out_bytes,
+                          scaled=scaled) <= VMEM_LIMIT
+
+    while True:
+        bk = next((b for b in bks if fits(bn, b)), bks[-1])
+        if fits(bn, bk) or bn == 128:
+            return bm, bn, bk
+        bn //= 2
+
+
+def tile_plan() -> dict:
+    """``(M, K, N, w_format) -> (bm, bn, bk, grid_steps)`` for every
+    ``quant_matmul`` call traced in this process (a trace-time record:
+    it costs nothing when the compiled program runs)."""
+    return dict(_TILE_PLAN)
+
+
 def quant_matmul(x_q: jax.Array, w: jax.Array, *,
                  x_scale: jax.Array | None = None,
                  w_scale: jax.Array | None = None,
                  w_format: str = "int8",
-                 bm: int = 128, bn: int = 128, bk: int = 128,
+                 bm: int | None = None, bn: int | None = None,
+                 bk: int | None = None,
                  out_dtype=None,
                  interpret: bool | None = None) -> jax.Array:
     """The single dispatch path for every quantized matmul.
@@ -82,6 +168,9 @@ def quant_matmul(x_q: jax.Array, w: jax.Array, *,
     ``out_dtype`` (bf16 by default) — the int32 accumulator never leaves
     VMEM.  The "lut" format is int32-only (its selection kernel models
     the paper's LUT array); scales there are applied as an XLA epilog.
+
+    Block sizes left as ``None`` come from :func:`pick_blocks`; explicit
+    ones win.
     """
     if w_format not in W_FORMATS:
         raise ValueError(f"w_format must be one of {W_FORMATS}: {w_format}")
@@ -91,6 +180,17 @@ def quant_matmul(x_q: jax.Array, w: jax.Array, *,
     m, k = mat.shape
     n = 2 * w.shape[1] if w_format == "int4_packed" else w.shape[1]
     scaled = x_scale is not None or w_scale is not None
+
+    if None in (bm, bn, bk):
+        res_dtype = out_dtype if out_dtype is not None else \
+            (jnp.bfloat16 if scaled else jnp.int32)
+        picked = pick_blocks(m, k, n, w_format, scaled=scaled,
+                             out_bytes=jnp.dtype(res_dtype).itemsize)
+        bm, bn, bk = (b if b is not None else p
+                      for b, p in zip((bm, bn, bk), picked))
+    _TILE_PLAN[(m, k, n, w_format)] = (
+        bm, bn, bk, (_round_up(m, bm) // bm) * (_round_up(n, bn) // bn)
+        * (_round_up(k, bk) // bk))
 
     xp = _pad_to(mat, bm, bk)
     wp = _pad_to(w, bk, bn // 2 if w_format == "int4_packed" else bn)

@@ -197,3 +197,93 @@ def test_linear_apply_pallas_matches_xla():
         bn = np.asarray(b, np.float32)
         rel = np.linalg.norm(an - bn) / (np.linalg.norm(bn) + 1e-9)
         assert rel < 0.05, (mode, rel)
+
+
+# --- block choice from each call's shape ---------------------------------
+
+def _proj_shapes(d, q_out, kv_out, ff, tp=1):
+    """(K, N) of the seven projections of one layer; a tp shard cuts N of
+    the column-parallel ones (q, k, v, gate, up) and K of o and down."""
+    return {"q": (d, q_out // tp), "k": (d, kv_out // tp),
+            "v": (d, kv_out // tp), "o": (q_out // tp, d),
+            "gate": (d, ff // tp), "up": (d, ff // tp), "down": (ff // tp, d)}
+
+
+# qwen3-4b: d 2560, 32 x 128 q, 8 x 128 kv, ff 9728; yi-6b: d 4096,
+# 32 x 128 q, 4 x 128 kv, ff 11008
+LAYERS = {"qwen3-4b": _proj_shapes(2560, 4096, 1024, 9728),
+          "qwen3-4b-tp4": _proj_shapes(2560, 4096, 1024, 9728, tp=4),
+          "yi-6b": _proj_shapes(4096, 4096, 512, 11008),
+          "yi-6b-tp4": _proj_shapes(4096, 4096, 512, 11008, tp=4)}
+
+
+def _up(x, mult):
+    return -(-x // mult) * mult
+
+
+@pytest.mark.parametrize("m", [1, 16, 300, 2048])
+@pytest.mark.parametrize("layer", sorted(LAYERS))
+def test_block_rule_at_projection_shapes(layer, m):
+    """Blocks divide the padded dims, pad no dim further than 128³ blocks
+    would, keep the (32, 128) int8 tiling and fit the scoped VMEM."""
+    for proj, (k, n) in LAYERS[layer].items():
+        bm, bn, bk = ops.pick_blocks(m, k, n, out_bytes=2)
+        where = (layer, proj, m, (bm, bn, bk))
+        assert bm % 32 == 0 and bn % 128 == 0 and bk % 128 == 0, where
+        assert _up(n, 128) % bn == 0 and _up(k, 128) % bk == 0, where
+        assert _up(m, bm) <= _up(m, 128), where
+        if m > 512:
+            assert _up(m, 128) % bm == 0, where
+        assert ops.vmem_bytes(bm, bn, bk, out_bytes=2) <= ops.VMEM_LIMIT, \
+            where
+
+
+@pytest.mark.parametrize("m,most", [(16, 400), (2048, 1600)])
+def test_tile_plan_grid_steps_per_layer(m, most):
+    """The traced calls of one qwen3-4b layer, as core.linear makes them
+    (per-tensor x scale, per-channel w scale, bf16 out), are recorded in
+    ``ops.tile_plan()`` with at most ``most`` grid steps in all (128³
+    blocks ran 6,160 in decode and 98,560 in a 2,048-row prefill)."""
+    shapes = LAYERS["qwen3-4b"]
+    for k, n in set(shapes.values()):
+        jax.eval_shape(
+            lambda x, w, ws: ops.quant_matmul(
+                x, w, x_scale=jnp.float32(0.1), w_scale=ws,
+                out_dtype=jnp.bfloat16, interpret=True),
+            jax.ShapeDtypeStruct((m, k), jnp.int8),
+            jax.ShapeDtypeStruct((k, n), jnp.int8),
+            jax.ShapeDtypeStruct((1, n), jnp.float32))
+    plan = ops.tile_plan()
+    steps = sum(plan[(m, k, n, "int8")][3] for k, n in shapes.values())
+    assert steps <= most, (steps, {p: plan[(m, k, n, "int8")]
+                                   for p, (k, n) in shapes.items()})
+
+
+def test_tile_plan_keeps_explicit_and_lut_blocks():
+    x, w = _rand_i8(40, 256), _rand_i8(256, 256)
+    ops.quant_matmul(x, w, bm=128, bn=128, bk=128, interpret=True)
+    assert ops.tile_plan()[(40, 256, 256, "int8")] == (128, 128, 128, 4)
+    ops.quant_matmul(x, w, bk=128, interpret=True)
+    assert ops.tile_plan()[(40, 256, 256, "int8")] == (64, 256, 128, 2)
+    ops.quant_matmul(x, w, w_format="lut", interpret=True)
+    assert ops.tile_plan()[(40, 256, 256, "lut")] == (128, 128, 128, 4)
+
+
+# N of 512 and 256 pick bn = N; at these M, bk is the whole K
+@pytest.mark.parametrize("scaled", [False, True], ids=["int32", "bf16"])
+@pytest.mark.parametrize("k,n", [(1024, 512), (768, 256)])
+@pytest.mark.parametrize("m", [1, 16, 300])
+def test_chosen_blocks_bit_identical_to_128(m, k, n, scaled):
+    x, w = _rand_i8(m, k), _rand_i8(k, n)
+    kw = {}
+    if scaled:
+        kw = dict(x_scale=jnp.float32(0.0123),
+                  w_scale=jnp.asarray(RNG.uniform(0.01, 0.1, (1, n)),
+                                      jnp.float32))
+    small = ops.quant_matmul(x, w, bm=128, bn=128, bk=128, interpret=True,
+                             **kw)
+    chosen = ops.quant_matmul(x, w, interpret=True, **kw)
+    assert ops.tile_plan()[(m, k, n, "int8")][:3] == (_up(m, 32), n, k)
+    assert chosen.dtype == small.dtype
+    np.testing.assert_array_equal(np.asarray(chosen, np.float32),
+                                  np.asarray(small, np.float32))

@@ -8,9 +8,10 @@ cache is off around these compiles — an entry written for a described
 chip cannot be read back without one.
 
 The main path (``ops.quant_matmul`` with the fused dequant epilogue,
-which ``core.linear`` uses for w8a8 and w4a8) compiles at qwen3-4b
-projection shapes.  Kernels the compiler refuses today are strict
-xfails that name the refusal, so a fix shows up as an XPASS.
+which ``core.linear`` uses for w8a8 and w4a8) compiles at qwen3-4b's
+seven projection shapes with the blocks it picks for each.  Kernels the
+compiler refuses today are strict xfails that name the refusal, so a fix
+shows up as an XPASS.
 """
 
 import re
@@ -21,8 +22,9 @@ import pytest
 
 # qwen3-4b projections (d_model 2560, 32 q heads / 8 kv heads x 128,
 # d_ff 9728): (K, N) of the int8 weight
-_PROJ = {"q": (2560, 4096), "o": (4096, 2560),
-         "gate": (2560, 9728), "down": (9728, 2560)}
+_PROJ = {"q": (2560, 4096), "k": (2560, 1024), "v": (2560, 1024),
+         "o": (4096, 2560), "gate": (2560, 9728), "up": (2560, 9728),
+         "down": (9728, 2560)}
 
 _BLOCK_RULE = r"divisible by 8 and 128"
 
@@ -76,12 +78,12 @@ def _expect_refusal(pattern, fn, *args):
         raise
 
 
-@pytest.mark.parametrize("m,proj", [(8, "q"), (8, "gate"), (8, "down"),
-                                    (2048, "o"), (2048, "gate"),
-                                    (2048, "down")])
+@pytest.mark.parametrize("proj", list(_PROJ))
+@pytest.mark.parametrize("m", [8, 16, 2048])
 def test_quant_matmul_compiles_at_qwen3_4b_shapes(shape_on_chip, m, proj):
-    """Decode (M=8) and prefill (M=2048) int8 matmuls with the in-kernel
-    dequant epilogue lower to a Mosaic kernel and compile."""
+    """Decode (M=8, 16) and prefill (M=2048) int8 matmuls with the
+    in-kernel dequant epilogue lower to a Mosaic kernel and compile, with
+    the blocks ``ops.pick_blocks`` chooses for the shape."""
     from repro.kernels import ops
     k, n = _PROJ[proj]
 
@@ -94,6 +96,8 @@ def test_quant_matmul_compiles_at_qwen3_4b_shapes(shape_on_chip, m, proj):
                         shape_on_chip((), jnp.float32),
                         shape_on_chip((1, n), jnp.float32))
     assert "tpu_custom_call" in compiled.as_text()
+    assert ops.tile_plan()[(m, k, n, "int8")][:3] == \
+        ops.pick_blocks(m, k, n, out_bytes=2)
 
 
 @pytest.mark.xfail(strict=True, raises=KernelRefused,
