@@ -208,12 +208,12 @@ def _stack_apply(params, cfg: ModelConfig, x, *, positions, caches=None,
 
     from repro.distributed.sharding import maybe_shard
 
-    def run_layer(p, spec, x, cache):
+    def run_layer(p, spec, x, cache, table=page_table):
         x = maybe_shard(x, "activation")   # pin (dp, ∅, ∅) between layers
         return _layer_apply(p, cfg, spec, x, positions=positions,
                             cache=cache, cache_index=cache_index,
                             enc_out=enc_out, causal=causal, mode=mode,
-                            page_table=page_table,
+                            page_table=table,
                             context_start=context_start)
 
     # prefix/suffix layers run OUTSIDE the scanned-and-checkpointed
@@ -232,39 +232,68 @@ def _stack_apply(params, cfg: ModelConfig, x, *, positions, caches=None,
         new_caches["prefix"].append(nc)
 
     # --- scanned blocks -----------------------------------------------------
+    # A given block cache (decode, wave, draft, verify, context prefill)
+    # rides the scan as a carry that each layer updates in place.  As
+    # scan xs/ys it would be sliced out per layer and restacked into a
+    # second pool-sized buffer, copying the whole stacked pool every
+    # step.  Paged pools are viewed as one pool of n_blocks × num_pages
+    # pages, and a layer addresses its own through the page table
+    # shifted by layer × num_pages, so its rows are written and gathered
+    # straight in the stack.  Other leaves (dense slabs, mamba state)
+    # read and write their layer's entry by dynamic index.  A fresh
+    # prefill (no cache given) emits its new per-layer caches as ys.
     if cfg.n_blocks:
+        blk_caches = caches["blocks"]
+        paged = page_table is not None and blk_caches is not None
+        n_pages = _pool_pages(blk_caches) if paged else 0
+        if paged:
+            blk_caches = _map_cache_leaves(
+                lambda a: a.reshape(-1, *a.shape[2:]), None, blk_caches,
+                paged)
+
         def block_body(carry, xs):
-            h, aux_acc = carry
-            blk_params, blk_caches = xs
-            blk_new = {}
+            h, aux_acc, cache = carry
+            blk_params, layer = xs
+            table = page_table + layer * n_pages if paged else page_table
+            emitted = {}
             for j, spec in enumerate(cfg.block_pattern):
-                c = blk_caches[str(j)] if blk_caches is not None else None
-                h, nc, aux = run_layer(blk_params[str(j)], spec, h, c)
+                key = str(j)
+                c = None if cache is None else _map_cache_leaves(
+                    None, lambda a: jax.lax.dynamic_index_in_dim(
+                        a, layer, keepdims=False), cache[key], paged)
+                h, nc, aux = run_layer(blk_params[key], spec, h, c, table)
                 aux_acc += aux
-                blk_new[str(j)] = nc
-            return (h, aux_acc), (blk_new if want_cache else 0)
+                if mode == "decode":
+                    cache = {**cache, key: _map_cache_leaves(
+                        lambda a, n: n,
+                        lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                            a, n, layer, 0), cache[key], paged, nc)}
+                else:
+                    emitted[key] = nc
+            return (h, aux_acc, cache), (emitted if mode == "prefill" else 0)
 
         body = block_body
         if cfg.remat and mode == "train":
             body = jax.checkpoint(block_body, prevent_cse=False)
 
-        xs = (params["blocks"], caches["blocks"])
+        xs = (params["blocks"], jnp.arange(cfg.n_blocks, dtype=jnp.int32))
+        carry = (x, total_aux, blk_caches)
         if _UNROLL_BLOCKS:
             emitted = []
-            carry = (x, total_aux)
             for i in range(cfg.n_blocks):
-                xs_i = jax.tree_util.tree_map(lambda a: a[i], xs)
-                carry, y = body(carry, xs_i)
+                carry, y = body(carry,
+                                jax.tree_util.tree_map(lambda a: a[i], xs))
                 emitted.append(y)
-            (x, total_aux) = carry
-            blk_caches_out = (jax.tree_util.tree_map(
-                lambda *ys: jnp.stack(ys), *emitted)
-                if want_cache else None)
+            ys = jax.tree_util.tree_map(lambda *ys: jnp.stack(ys), *emitted)
         else:
-            (x, total_aux), blk_caches_out = jax.lax.scan(
-                body, (x, total_aux), xs)
-        if want_cache:
-            new_caches["blocks"] = blk_caches_out
+            carry, ys = jax.lax.scan(body, carry, xs)
+        x, total_aux, blk_caches = carry
+        if mode == "decode":
+            new_caches["blocks"] = _map_cache_leaves(
+                lambda a: a.reshape(cfg.n_blocks, n_pages, *a.shape[1:]),
+                None, blk_caches, paged)
+        elif want_cache:
+            new_caches["blocks"] = ys
 
     for p, spec, c in zip(params["suffix"], cfg.suffix_pattern,
                           caches["suffix"]):
@@ -349,6 +378,25 @@ def forward(params, cfg: ModelConfig, tokens, *, extra_embeds=None,
     return _logits(params, cfg, x), aux
 
 
+def _cache_tree(cfg: ModelConfig, layer_cache) -> dict:
+    """The cache tree of the whole stack from ``layer_cache(spec)``, one
+    layer's zero caches.  Each scanned-block leaf is one allocation of
+    zeros in the stacked shape, so building the pool never holds a leaf
+    twice."""
+    def stacked(spec: LayerSpec):
+        one = jax.eval_shape(lambda: layer_cache(spec))
+        return jax.tree_util.tree_map(
+            lambda a: jnp.zeros((cfg.n_blocks, *a.shape), a.dtype), one)
+
+    return {
+        "prefix": [layer_cache(s) for s in cfg.prefix_pattern],
+        "blocks": {str(j): stacked(s)
+                   for j, s in enumerate(cfg.block_pattern)}
+        if cfg.n_blocks else None,
+        "suffix": [layer_cache(s) for s in cfg.suffix_pattern],
+    }
+
+
 def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> dict:
     """Decode caches: dense per-slot slabs, or — when
     ``cfg.cache_mode == "paged"`` — shared page pools (see
@@ -375,19 +423,7 @@ def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> dict:
             return {"mamba": init_mamba_cache(cfg, batch)}
         return {}
 
-    def stacked(spec: LayerSpec):
-        one = layer_cache(spec)
-        return jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.n_blocks, *a.shape))
-            .copy() if cfg.n_blocks else a, one)
-
-    return {
-        "prefix": [layer_cache(s) for s in cfg.prefix_pattern],
-        "blocks": {str(j): stacked(s)
-                   for j, s in enumerate(cfg.block_pattern)}
-        if cfg.n_blocks else None,
-        "suffix": [layer_cache(s) for s in cfg.suffix_pattern],
-    }
+    return _cache_tree(cfg, layer_cache)
 
 
 def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int,
@@ -407,19 +443,7 @@ def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int,
             return {"mamba": init_mamba_cache(cfg, batch)}
         return {}
 
-    def stacked(spec: LayerSpec):
-        one = layer_cache(spec)
-        return jax.tree_util.tree_map(
-            lambda a: jnp.broadcast_to(a[None], (cfg.n_blocks, *a.shape))
-            .copy() if cfg.n_blocks else a, one)
-
-    return {
-        "prefix": [layer_cache(s) for s in cfg.prefix_pattern],
-        "blocks": {str(j): stacked(s)
-                   for j, s in enumerate(cfg.block_pattern)}
-        if cfg.n_blocks else None,
-        "suffix": [layer_cache(s) for s in cfg.suffix_pattern],
-    }
+    return _cache_tree(cfg, layer_cache)
 
 
 def prefill(params, cfg: ModelConfig, tokens, *, extra_embeds=None,
@@ -485,11 +509,36 @@ def _is_block_leaf(path) -> bool:
     return isinstance(head, jax.tree_util.DictKey) and head.key == "blocks"
 
 
+def _leaf_key(path):
+    return path[-1].key if hasattr(path[-1], "key") else None
+
+
+def _map_cache_leaves(on_pool, on_other, tree, paged, *rest):
+    """Map a layer-stacked cache subtree leaf by leaf: a page pool (a
+    sequence leaf, when ``paged``) takes ``on_pool``, any other leaf
+    ``on_other``, and ``None`` keeps the leaf as it is.  ``rest`` are
+    trees of the same structure whose leaves are passed alongside."""
+    def f(path, a, *r):
+        pool = paged and _leaf_key(path) in _SEQ_CACHE_KEYS
+        fn = on_pool if pool else on_other
+        return a if fn is None else fn(a, *r)
+
+    return jax.tree_util.tree_map_with_path(f, tree, *rest)
+
+
+def _pool_pages(tree) -> int:
+    """Pages per layer in a stacked paged cache subtree (every pool is
+    built with the same ``num_pages``); 0 when it holds no pool."""
+    leaves = jax.tree_util.tree_flatten_with_path(tree)[0]
+    return next((a.shape[1] for path, a in leaves
+                 if _leaf_key(path) in _SEQ_CACHE_KEYS), 0)
+
+
 def _grow_caches(cfg, caches, cur_len, max_len):
     """Pad prefill KV caches out to the decode budget (key-aware: SSM
     conv/state caches have no sequence axis and are left alone)."""
     def pad_leaf(path, a):
-        key = path[-1].key if hasattr(path[-1], "key") else None
+        key = _leaf_key(path)
         if key not in _SEQ_CACHE_KEYS:
             return a
         # seq axis: 1 for per-layer caches, 2 under the block-stack axis
@@ -530,7 +579,7 @@ def merge_slot_paged_caches(big, one, slot, pages):
     pages = jnp.asarray(pages, jnp.int32)
 
     def put(path, b_leaf, s_leaf):
-        key = path[-1].key if hasattr(path[-1], "key") else None
+        key = _leaf_key(path)
         blk = _is_block_leaf(path)
         if key not in _SEQ_CACHE_KEYS:
             b_ax = 1 if blk else 0
@@ -568,7 +617,7 @@ def scatter_prefill_paged_caches(big, one, slot, row, start):
     row = jnp.asarray(row, jnp.int32)
 
     def put(path, b_leaf, s_leaf):
-        key = path[-1].key if hasattr(path[-1], "key") else None
+        key = _leaf_key(path)
         blk = _is_block_leaf(path)
         if key not in _SEQ_CACHE_KEYS:
             b_ax = 1 if blk else 0
@@ -598,7 +647,7 @@ def copy_paged_cache_page(caches, src, dst):
     dst = jnp.asarray(dst, jnp.int32)
 
     def cp(path, leaf):
-        key = path[-1].key if hasattr(path[-1], "key") else None
+        key = _leaf_key(path)
         if key not in _SEQ_CACHE_KEYS:
             return leaf
         if _is_block_leaf(path):
@@ -633,7 +682,7 @@ def extract_cache_pages(caches, pages, pad_to: int | None = None) -> list[dict]:
     leaves = jax.tree_util.tree_flatten_with_path(caches)[0]
     cols: dict[int, tuple[np.ndarray, bool]] = {}
     for i, (path, leaf) in enumerate(leaves):
-        key = path[-1].key if hasattr(path[-1], "key") else None
+        key = _leaf_key(path)
         if key not in _SEQ_CACHE_KEYS:
             continue
         blk = _is_block_leaf(path)
@@ -663,7 +712,7 @@ def insert_cache_pages(caches, pages, payloads, pad_to: int | None = None):
     leaves, treedef = jax.tree_util.tree_flatten_with_path(caches)
     out = []
     for i, (path, leaf) in enumerate(leaves):
-        key = path[-1].key if hasattr(path[-1], "key") else None
+        key = _leaf_key(path)
         if key in _SEQ_CACHE_KEYS and i in payloads[0]:
             blk = _is_block_leaf(path)
             rows = np.stack([p[i] for p in payloads],

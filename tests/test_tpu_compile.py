@@ -192,3 +192,115 @@ def test_quant_matmul_compiles_tensor_parallel(topo):
             interpret=False),
         on((8, k), jnp.int8, P()), on((k, n), jnp.int8, P(None, "model")),
         on((), jnp.float32, P()), on((1, n), jnp.float32, P(None, "model")))
+
+
+# ---------------------------------------------------------------------------
+# Stage programs at qwen3-4b's widths with the benchmark cell's pool
+# ---------------------------------------------------------------------------
+
+# the chat cell's serving settings: 16 slots over 1,537 pages of 16 rows
+_CELL = dict(batch=16, max_len=2560, prefill_len=2048, decode_chunk=8,
+             cache_mode="paged", page_size=16, num_pages=1537,
+             quant_mode="w8a8_nibble", quant_backend="pallas")
+
+
+@pytest.fixture(scope="module")
+def cell_programs(topo):
+    """``decode_chunk`` and ``prefill`` of an engine at qwen3-4b's widths
+    with 4 and 8 layers, compiled for one described v5e chip:
+    ``{(stage, layers): (temp bytes, pool bytes, pool shapes, HLO)}``.
+    The engine is built on abstract params and caches, so nothing the
+    size of a layer is allocated here."""
+    from jax.sharding import SingleDeviceSharding
+
+    from repro.configs import get_config
+    from repro.kernels import ops
+    from repro.models import model_init
+    from repro.serve import Engine, ServeConfig
+    from repro.serve import engine as engine_mod
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=one_chip), tree)
+
+    def arg(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    b, c = _CELL["batch"], _CELL["decode_chunk"]
+    max_pages = _CELL["max_len"] // _CELL["page_size"]
+    out = {}
+    init = engine_mod.init_caches
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(ops, "_on_tpu", lambda: True)   # lower Pallas to Mosaic
+        mp.setattr(engine_mod, "init_caches",
+                   lambda *a: jax.eval_shape(lambda: init(*a)))
+        for layers in (4, 8):
+            cfg = get_config("qwen3-4b").replace(n_layers=layers)
+            params = jax.eval_shape(
+                lambda: model_init(jax.random.PRNGKey(0), cfg))
+            engine = Engine(cfg, params, ServeConfig(**_CELL))
+            caches = engine._caches
+            pool = sum(a.size * a.dtype.itemsize
+                       for a in jax.tree_util.tree_leaves(caches))
+            k = caches["blocks"]["0"]["attn"]["k"].shape
+            shapes = (k, (k[0] * k[1],) + k[2:])  # stacked, and as one pool
+            head = (on_chip(params), on_chip(caches))
+            args = {
+                "decode_chunk": head + (
+                    arg((b, 1), jnp.int32), arg((b,), jnp.int32),
+                    arg((b,), jnp.bool_), arg((b,), jnp.int32),
+                    arg((b, max_pages), jnp.int32), arg((c, b), jnp.int32),
+                    arg((c, b), jnp.bool_), arg((b, 2), jnp.uint32),
+                    arg((b,), jnp.int32)),
+                "prefill": head + (
+                    arg((1, _CELL["prefill_len"]), jnp.int32),
+                    arg((), jnp.int32), arg((), jnp.int32),
+                    arg((max_pages,), jnp.int32), arg((2,), jnp.uint32)),
+            }
+            programs = engine.stage_programs()
+            for stage, a in args.items():
+                compiled = programs[stage].jit_fn.lower(*a).compile()
+                temp = compiled.memory_analysis().temp_size_in_bytes
+                out[stage, layers] = (temp, pool, shapes,
+                                      compiled.as_text())
+    return out
+
+
+def _pool_shaped_copies(hlo, shapes):
+    """Instructions of the compiled program that copy, transpose or
+    broadcast into a buffer of one of ``shapes``."""
+    dims = "|".join(",".join(map(str, s)) for s in shapes)
+    result = re.compile(r"= \w+\[(%s)\]\{[^}]*\} (copy|transpose|broadcast)"
+                        r"(-start)?\(" % dims)
+    return [line.strip()[:200] for line in hlo.splitlines()
+            if result.search(line)]
+
+
+def test_decode_chunk_updates_the_pool_in_place(cell_programs):
+    """The decode chunk holds no copy of the stacked KV pool: its temp
+    does not grow with depth, and from 8 layers on it is below half the
+    pool.  At 4 layers it is still below one pool (a pool-sized buffer
+    would pass that): most of it is one layer's gathered context, 2 x
+    84 MB, the same at any depth."""
+    (t4, p4, s4, h4), (t8, p8, s8, h8) = (cell_programs["decode_chunk", n]
+                                          for n in (4, 8))
+    assert not _pool_shaped_copies(h4, s4)
+    assert not _pool_shaped_copies(h8, s8)
+    assert t8 <= t4, (t4, t8)
+    assert t4 < p4, (t4, p4)
+    assert t8 < p8 / 2, (t8, p8)
+
+
+def test_prefill_holds_no_pool_copy(cell_programs):
+    """The prefill program merges a prompt's pages into the pool in
+    place.  Its temp carries the prompt's attention activations and the
+    fresh per-layer cache it emits, so it grows with depth, but by less
+    than half of what the pool grows by."""
+    (t4, p4, s4, h4), (t8, p8, s8, h8) = (cell_programs["prefill", n]
+                                          for n in (4, 8))
+    assert not _pool_shaped_copies(h4, s4)
+    assert not _pool_shaped_copies(h8, s8)
+    assert t8 - t4 < (p8 - p4) / 2, (t4, t8, p4, p8)
