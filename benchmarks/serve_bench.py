@@ -9,11 +9,11 @@ the continuous-batching engine, per workload shape:
                   refill mid-stream (the continuous-batching case; the
                   per-slot position vector is what makes it possible).
 
-Grid: {dense, w8a8_nibble} × {xla, pallas} × {uniform, staggered} ×
-{dense, paged} cache on a reduced config, plus an **overcommitted
-pool** pair: the same paged pool sized well below the sum of worst-case
-page counts, driven once with ``alloc_mode="reserve"`` (admission must
-serialize on worst-case bookings) and once with
+Grid: {dense, w8a8_nibble} × {xla, pallas} × {uniform, staggered} on
+a reduced config over a parity-sized page pool, plus an
+**overcommitted pool** pair: the same pool sized well below the sum of
+worst-case page counts, driven once with ``alloc_mode="reserve"``
+(admission must serialize on worst-case bookings) and once with
 ``alloc_mode="incremental"`` (pages booked per live token,
 evict-and-resume preemption when the pool runs dry).  The
 ``concurrency`` and ``occupancy`` columns are the point: incremental
@@ -47,9 +47,9 @@ and the replayed decode steps it saved.
 CPU wall-clock is a functional proxy (pallas runs in interpret mode —
 correctness, not speed); the uniform-vs-staggered *ratio*, the latency
 percentiles and the per-request cache HBM column are the transferable
-signal.  ``cache_kb_per_req`` is the point of the paged cache: dense
-reserves the full ``max_len`` slab per request, paged reserves only the
-pages its live tokens touch.  Results land in ``BENCH_serve.json``.
+signal.  ``cache_kb_per_req`` is the point of the paged cache: a
+request reserves only the pages its live tokens touch, not the full
+``max_len``.  Results land in ``BENCH_serve.json``.
 
     PYTHONPATH=src python benchmarks/serve_bench.py [--json out.json]
 """
@@ -74,9 +74,8 @@ REQUESTS = 8
 STAGGER_S = 0.05
 PAGE_SIZE = 4
 # the slot budget is provisioned for a worst case twice the actual
-# workload (as a production deployment must be): dense reserves the
-# whole slab per request, paged reserves only live pages — the gap is
-# the cache_kb_per_req column
+# workload (as a production deployment must be): a request reserves
+# only live pages, not max_len — the gap is the cache_kb_per_req column
 MAX_LEN = 2 * (PROMPT_BUDGET + NEW_TOKENS)
 # overcommitted pool: every request's worst case is ceil((16+16-1)/4)
 # = 8 pages, so 4 slots want 32 + trash; 17 pages (capacity 16 = two
@@ -142,8 +141,8 @@ def _attach_capacity(row, engine, scfg, *, requests, stagger,
             / max(row["tok_per_s"], 1e-9), 1)
 
 
-def _bench_one(cfg, params, quant, backend, workload, cache_mode,
-               alloc_mode="reserve", num_pages=None, prefix_cache=False,
+def _bench_one(cfg, params, quant, backend, workload,
+               alloc_mode="reserve", num_pages=0, prefix_cache=False,
                shared_prefix=0.0, arrival_mode="uniform", decode_chunk=8,
                spec=False, tp=1, dp=1, prefill_chunk=0, admit_group=1,
                swap_mode="off", requests=REQUESTS):
@@ -152,9 +151,8 @@ def _bench_one(cfg, params, quant, backend, workload, cache_mode,
                        prefill_len=PROMPT_BUDGET, decode_chunk=decode_chunk,
                        alloc_mode=alloc_mode, prefix_cache=prefix_cache,
                        quant_mode=quant, quant_backend=backend,
-                       cache_mode=cache_mode, page_size=PAGE_SIZE,
-                       num_pages=num_pages, spec_decode=spec,
-                       spec_k=SPEC_K,
+                       page_size=PAGE_SIZE, num_pages=num_pages,
+                       spec_decode=spec, spec_k=SPEC_K,
                        spec_quant_mode=SPEC_DRAFT if spec else None,
                        tp=tp, prefill_chunk=prefill_chunk,
                        admit_group=admit_group, swap_mode=swap_mode)
@@ -195,17 +193,16 @@ def _bench_one(cfg, params, quant, backend, workload, cache_mode,
     elif counts != expected:
         raise RuntimeError(f"engine recompiled during benchmark: {counts} "
                            f"(expected {expected})")
-    # a paged drain must hand every page back once the prefix index
-    # lets go — a leak in a benchmark run invalidates its numbers
-    if cache_mode == "paged":
-        engine.release_prefix_cache()
-        leaked = engine.leaked_pages()
-        if leaked:
-            raise RuntimeError(f"page leak: {leaked} page(s) still booked "
-                               f"after drain")
+    # a drain must hand every page back once the prefix index lets go —
+    # a leak in a benchmark run invalidates its numbers
+    engine.release_prefix_cache()
+    leaked = engine.leaked_pages()
+    if leaked:
+        raise RuntimeError(f"page leak: {leaked} page(s) still booked "
+                           f"after drain")
     row = {"workload": workload, "quant": quant, "backend": backend,
-           "cache": cache_mode, "alloc": alloc_mode if cache_mode == "paged"
-           else "-", "prefix": "on" if prefix_cache else "-", **r}
+           "cache": "paged", "alloc": alloc_mode,
+           "prefix": "on" if prefix_cache else "-", **r}
     row["spec"] = "on" if spec else "-"
     row["tail"] = "on" if (wave or swap_mode != "off") else "-"
     _attach_capacity(row, engine, scfg, requests=requests, stagger=stagger,
@@ -255,13 +252,11 @@ def run(json_path: str | None = None):
     rows = []
     for quant, backend in GRID:
         for workload in ("uniform", "staggered"):
-            for cache_mode in ("dense", "paged"):
-                r, warn = _bench_one(cfg, params, quant, backend, workload,
-                                     cache_mode)
-                rows.append(r)
-                if warn:
-                    yield warn
-                yield _csv(r)
+            r, warn = _bench_one(cfg, params, quant, backend, workload)
+            rows.append(r)
+            if warn:
+                yield warn
+            yield _csv(r)
     # overcommitted pool: same pool, reserve vs incremental bookkeeping,
     # plus incremental with the host-tier swap — its preemptions resume
     # by page copy, so swap_out/swap_in fire and replay_steps_saved
@@ -269,7 +264,7 @@ def run(json_path: str | None = None):
     for alloc_mode, swap in (("reserve", "off"), ("incremental", "off"),
                              ("incremental", "host")):
         r, warn = _bench_one(cfg, params, "dense", "xla", "overcommit",
-                             "paged", alloc_mode=alloc_mode,
+                             alloc_mode=alloc_mode,
                              num_pages=OVERCOMMIT_PAGES, swap_mode=swap)
         rows.append(r)
         if warn:
@@ -279,7 +274,7 @@ def run(json_path: str | None = None):
     # the hit-rate column and the ttft delta are the payoff
     for prefix_cache in (False, True):
         r, warn = _bench_one(cfg, params, "dense", "xla", "shared",
-                             "paged", prefix_cache=prefix_cache,
+                             prefix_cache=prefix_cache,
                              shared_prefix=SHARED_PREFIX)
         rows.append(r)
         if warn:
@@ -293,7 +288,7 @@ def run(json_path: str | None = None):
     for arrival in ("uniform", "bursty"):
         for spec in (False, True):
             r, warn = _bench_one(cfg, params, "dense", "xla", arrival,
-                                 "paged", alloc_mode="incremental",
+                                 alloc_mode="incremental",
                                  arrival_mode=arrival, decode_chunk=1,
                                  spec=spec)
             rows.append(r)
@@ -310,7 +305,7 @@ def run(json_path: str | None = None):
     # are the per-run maximum and burst luck dominates the comparison
     for tail in (False, True):
         r, warn = _bench_one(cfg, params, "dense", "xla", "burst_tail",
-                             "paged", alloc_mode="incremental",
+                             alloc_mode="incremental",
                              num_pages=OVERCOMMIT_PAGES,
                              arrival_mode="bursty", requests=2 * REQUESTS,
                              prefill_chunk=4 if tail else 0,
@@ -327,7 +322,7 @@ def run(json_path: str | None = None):
     # devices present (checked at the top)
     for tp, dp in MESH_TRIO:
         r, warn = _bench_one(cfg, params, "w8a8_nibble", "xla", "mesh",
-                             "paged", alloc_mode="incremental",
+                             alloc_mode="incremental",
                              prefix_cache=True, shared_prefix=SHARED_PREFIX,
                              tp=tp, dp=dp)
         rows.append(r)
@@ -347,13 +342,13 @@ def run(json_path: str | None = None):
                     "worst case 2x the workload; cache=paged uses "
                     f"page_size={PAGE_SIZE} pools + page-table "
                     "indirection and cache_kb_per_req is the per-request "
-                    "KV reservation (dense: the max_len slab; paged: "
-                    "allocated pages only). occupancy = mean fraction of "
-                    "pool pages in use per decode chunk; concurrency = "
+                    "KV reservation (allocated pages only). occupancy "
+                    "= mean fraction of pool pages in use per decode "
+                    "chunk; concurrency = "
                     "mean admitted requests per chunk. The overcommit "
                     f"rows share one {OVERCOMMIT_PAGES}-page pool — "
                     "below the 4-slot worst-case sum of 33 pages (and "
-                    "far below the 65-page dense-parity default): "
+                    "far below the 65-page parity default): "
                     "alloc=reserve must serialize admissions on "
                     "worst-case bookings, alloc=incremental books pages "
                     "per live token (evict-and-resume preemption when "

@@ -104,7 +104,6 @@ class Knobs:
     max_len: int
     prefill_len: int = 0
     decode_chunk: int = 8
-    cache_mode: str = "dense"
     page_size: int = 8
     num_pages: int | None = None
     alloc_mode: str = "reserve"
@@ -123,8 +122,8 @@ class Knobs:
         return cls(batch=scfg.batch, max_len=scfg.max_len,
                    prefill_len=scfg.prefill_len,
                    decode_chunk=scfg.decode_chunk,
-                   cache_mode=scfg.cache_mode, page_size=scfg.page_size,
-                   num_pages=scfg.num_pages, alloc_mode=scfg.alloc_mode,
+                   page_size=scfg.page_size, num_pages=scfg.num_pages,
+                   alloc_mode=scfg.alloc_mode,
                    spec_decode=scfg.spec_decode, spec_k=scfg.spec_k,
                    prefill_chunk=scfg.prefill_chunk,
                    admit_group=scfg.admit_group, swap_mode=scfg.swap_mode,
@@ -155,19 +154,13 @@ class Knobs:
 
     # --- derived geometry (identical to the engine's resolution) ------
     @property
-    def paged(self) -> bool:
-        return self.cache_mode == "paged"
-
-    @property
     def wave(self) -> bool:
         return self.prefill_chunk > 0 or self.admit_group > 1
 
     @property
     def resolved_num_pages(self) -> int:
-        """Pool size incl. the reserved trash page (0 in dense mode):
-        ``num_pages`` or capacity parity with the dense slab."""
-        if not self.paged:
-            return 0
+        """Pool size incl. the reserved trash page: ``num_pages`` or
+        capacity parity (every slot's ``max_len`` at once)."""
         return (self.num_pages
                 or self.batch * (self.max_len // self.page_size) + 1)
 
@@ -315,7 +308,6 @@ class _Sim:
         self.shape = shape
         self.c = costs
         self.ctb = cache_token_bytes
-        self.paged = knobs.paged
         self.incremental = knobs.alloc_mode == "incremental"
         self.wave = knobs.wave
         self.spec = knobs.spec_decode
@@ -367,20 +359,12 @@ class _Sim:
     def _validate(self, k: Knobs):
         if k.batch < 1:
             raise CapacityError(f"batch must be >= 1, got {k.batch}")
-        if self.incremental and not self.paged:
-            raise CapacityError("alloc_mode='incremental' requires "
-                                "cache_mode='paged'")
-        if self.paged:
-            if k.page_size < 1:
-                raise CapacityError(f"page_size must be >= 1, got "
-                                    f"{k.page_size}")
-            if k.max_len % k.page_size:
-                raise CapacityError(f"max_len {k.max_len} must be a "
-                                    f"multiple of page_size "
-                                    f"{k.page_size}")
-        if (self.wave or self.swap) and not self.paged:
-            raise CapacityError("prefill_chunk/admit_group/swap_mode "
-                                "require cache_mode='paged'")
+        if k.page_size < 1:
+            raise CapacityError(f"page_size must be >= 1, got "
+                                f"{k.page_size}")
+        if k.max_len % k.page_size:
+            raise CapacityError(f"max_len {k.max_len} must be a multiple "
+                                f"of page_size {k.page_size}")
         if self.spec and k.spec_k < 1:
             raise CapacityError(f"spec_k must be >= 1, got {k.spec_k}")
         if self.wave and not (k.prefill_chunk or k.prefill_len):
@@ -403,12 +387,11 @@ class _Sim:
                     f"prefill_len={self.k.prefill_len}")
             budget = self.k.max_len - p_len
             clamped = min(self.shape.new_tokens, budget)
-            if self.paged:
-                need = pages_needed(p_len + clamped - 1, self.ps)
-                if need > self.capacity:
-                    raise CapacityError(
-                        f"request needs {need} pages but the pool "
-                        f"capacity is {self.capacity}")
+            need = pages_needed(p_len + clamped - 1, self.ps)
+            if need > self.capacity:
+                raise CapacityError(
+                    f"request needs {need} pages but the pool capacity "
+                    f"is {self.capacity}")
             req = _SimReq(
                 seq=i, arrival=float(draw.arrivals[i]),
                 prio=int(draw.prios[i]), p_len=p_len, max_new=clamped,
@@ -445,8 +428,6 @@ class _Sim:
         return pages_needed(rows, self.ps)
 
     def _can_admit(self, req: _SimReq) -> bool:
-        if not self.paged:
-            return True
         return self.free >= self._alloc_pages_for(req)
 
     def _evictable_pages(self, now: float, cutoff: int) -> int:
@@ -502,8 +483,8 @@ class _Sim:
                 return
             cutoff = self._eff(cand, now)
             if free_slot is None:
-                if self.paged and (self._evictable_pages(now, cutoff)
-                                   < self._alloc_pages_for(cand)):
+                if (self._evictable_pages(now, cutoff)
+                        < self._alloc_pages_for(cand)):
                     return
                 victim = self._pick_victim(now, below=cutoff)
                 if victim is None:
@@ -538,10 +519,7 @@ class _Sim:
             self.prefill_next[slot] = 0
             self.active[slot] = False
             return
-        if self.paged:
-            self._book(slot, req)
-        else:
-            req.cache_rows = self.k.max_len
+        self._book(slot, req)
         # one serialized monolithic prefill dispatch
         self.t += self.c.prefill_s + self.c.overhead_s
         resumed = req.ntok > 0
@@ -646,8 +624,7 @@ class _Sim:
     def _sample_stats(self) -> None:
         self.stat_samples += 1
         self.stat_running += sum(r is not None for r in self.slots)
-        if self.paged:
-            self.stat_in_use += self.capacity - self.free
+        self.stat_in_use += self.capacity - self.free
 
     def _emit(self, slot: int, n: int) -> None:
         """Commit ``n`` tokens to the slot's stream: replays first (no
@@ -772,8 +749,7 @@ class _Sim:
             "replay_steps_saved": self.replay_steps_saved,
             "concurrency": self.stat_running / max(1, self.stat_samples),
             "occupancy": (self.stat_in_use
-                          / max(1, self.stat_samples * self.capacity)
-                          if self.paged else 0.0),
+                          / max(1, self.stat_samples * self.capacity)),
             "truncated": int(sum(r.truncated for r in self.all_reqs)),
         }
         if done and self.infeasible is None:

@@ -20,16 +20,12 @@ __all__ = ["knob_grid", "search", "table_lines"]
 def knob_grid(shape: WorkloadShape, *, batch: int, max_len: int,
               prefill_len: int, page_size_opts=(4, 8),
               small: bool = False):
-    """The structured knob space: one dense baseline sweep plus paged
-    variants crossing allocation mode, pool size, wave prefill, swap
-    and speculative decoding.  ``small`` is the CI grid."""
+    """The structured knob space: pools crossing page size, pool size
+    (down from parity), allocation mode, wave prefill, swap and
+    speculative decoding.  ``small`` is the CI grid."""
     chunks = (1, 8) if small else (1, 4, 8)
     pools_frac = (0.5, 1.0) if small else (0.25, 0.5, 1.0)
     cells = []
-    for dc in chunks:
-        cells.append(Knobs(batch=batch, max_len=max_len,
-                           prefill_len=prefill_len, decode_chunk=dc,
-                           cache_mode="dense"))
     for ps in (page_size_opts[:1] if small else page_size_opts):
         parity = batch * (max_len // ps) + 1
         pools = sorted({max(2, int(parity * f)) | 1 for f in pools_frac})
@@ -39,15 +35,13 @@ def knob_grid(shape: WorkloadShape, *, batch: int, max_len: int,
                     cells.append(Knobs(
                         batch=batch, max_len=max_len,
                         prefill_len=prefill_len, decode_chunk=dc,
-                        cache_mode="paged", page_size=ps, num_pages=np_,
-                        alloc_mode=alloc))
+                        page_size=ps, num_pages=np_, alloc_mode=alloc))
             # wave prefill + grouped admission (+ host swap)
             for swap in (("off",) if small else ("off", "host")):
                 cells.append(Knobs(
                     batch=batch, max_len=max_len,
                     prefill_len=prefill_len, decode_chunk=8,
-                    cache_mode="paged", page_size=ps, num_pages=np_,
-                    alloc_mode="incremental",
+                    page_size=ps, num_pages=np_, alloc_mode="incremental",
                     prefill_chunk=max(1, prefill_len // 4),
                     admit_group=batch, swap_mode=swap))
             # speculative decoding on the parity pool
@@ -55,7 +49,7 @@ def knob_grid(shape: WorkloadShape, *, batch: int, max_len: int,
                 cells.append(Knobs(
                     batch=batch, max_len=max_len,
                     prefill_len=prefill_len, decode_chunk=1,
-                    cache_mode="paged", page_size=ps, num_pages=parity,
+                    page_size=ps, num_pages=parity,
                     alloc_mode="incremental", spec_decode=True,
                     spec_k=k, quant_mode="w8a8_nibble"))
     # Knobs is frozen/hashable: drop duplicate cells, keep first-seen
@@ -86,7 +80,7 @@ def search(cfg, shape: WorkloadShape, cells, *, objective: str,
         if admissible and ttft_slo_ms is not None:
             admissible = pred["ttft_p99_ms"] <= ttft_slo_ms
         if admissible and objective == "min-pages":
-            admissible = (knobs.paged and pred["preemptions"] == 0)
+            admissible = pred["preemptions"] == 0
         results.append({"knobs": knobs, "prediction": pred,
                         "admissible": admissible,
                         "reason": pred.get("infeasible_reason")})
@@ -101,7 +95,7 @@ def search(cfg, shape: WorkloadShape, cells, *, objective: str,
 
 
 def table_lines(results, winner):
-    yield ("cache,alloc,page_size,pool_pages,decode_chunk,wave,swap,"
+    yield ("alloc,page_size,pool_pages,decode_chunk,wave,swap,"
            "spec,tok_per_s,ttft_p50_ms,ttft_p99_ms,preempt,"
            "cache_kb_per_req,admissible")
     for r in sorted(results,
@@ -113,13 +107,13 @@ def table_lines(results, winner):
         mark = " <== winner" if winner is not None \
             and k == winner["knobs"] else ""
         if p is None or "tok_per_s" not in p:
-            yield (f"{k.cache_mode},{k.alloc_mode},{k.page_size},"
+            yield (f"{k.alloc_mode},{k.page_size},"
                    f"{k.resolved_num_pages},{k.decode_chunk},"
                    f"{'on' if k.wave else '-'},{k.swap_mode},"
                    f"{k.spec_k if k.spec_decode else '-'},"
                    f"-,-,-,-,-,no ({r.get('reason')})")
             continue
-        yield (f"{k.cache_mode},{k.alloc_mode},{k.page_size},"
+        yield (f"{k.alloc_mode},{k.page_size},"
                f"{k.resolved_num_pages},{k.decode_chunk},"
                f"{'on' if k.wave else '-'},{k.swap_mode},"
                f"{k.spec_k if k.spec_decode else '-'},"

@@ -101,14 +101,6 @@ class ModelConfig:
     attn_impl: str = "chunked"         # "chunked" | "flash" (Pallas kernel)
     kv_cache_dtype: str = "bf16"       # "bf16" | "int8" (paper-aligned:
     #   per-token-per-head symmetric int8 KV storage halves decode bytes)
-    cache_mode: str = "dense"          # "dense" (per-slot max_len slab) |
-    #   "paged" (shared page pools + page-table indirection: the paper's
-    #   fixed-width-reusable-unit idea applied to KV storage — capacity
-    #   scales with live tokens, not worst-case request shape)
-    page_size: int = 16                # tokens per KV page (paged mode)
-    num_pages: int = 0                 # shared pool size incl. the trash
-    #   page; 0 = auto (slots × max_len / page_size + 1, capacity parity
-    #   with the dense slab — shrink it to bank the HBM win)
     attn_core_bypass: bool = False     # ablation: skip the score/softmax
     #   core (projections kept) — used by the roofline attention-byte
     #   measurement (EXPERIMENTS.md §Perf), never in real runs

@@ -273,10 +273,12 @@ def _mamba_cache_spec(mesh, leafk: str, stacked: bool) -> P:
     return P(*((None,) + spec if stacked else spec))
 
 
-def cache_specs(cfg: ModelConfig, caches: Any, mesh, *,
-                batch: int) -> Any:
+def cache_specs(cfg: ModelConfig, caches: Any, mesh, *, batch: int,
+                paged: bool) -> Any:
+    """Partition specs for a cache tree.  ``paged`` says which layout the
+    caller built: a serving engine's page pools, or the unpaged
+    per-sequence caches a plain ``decode_step`` reads."""
     seq_to_dp = batch == 1
-    paged = cfg.cache_mode == "paged"
 
     def rule(path, leaf):
         keys = [k for k in path]
@@ -286,7 +288,7 @@ def cache_specs(cfg: ModelConfig, caches: Any, mesh, *,
             # paged pools drop the batch axis — (num_pages, page_size,
             # KVH, D) with int8 scale pools at D=1 — and get their own
             # head-or-sequence rule; mamba state stays per-slot (B, ...)
-            # even in paged mode, so only the KV/MLA leaves switch
+            # next to the pools, so only the KV/MLA leaves switch
             spec = (_paged_kv_spec(cfg, mesh, stacked) if paged
                     else _kv_spec(cfg, mesh, batch, stacked, seq_to_dp))
         elif leafk in ("c_kv", "k_rope"):

@@ -145,7 +145,8 @@ def build_cell(cfg, case, mesh, *, quant_moments: bool):
     params = jax.eval_shape(lambda: model_init(jax.random.PRNGKey(0), cfg))
     ins = decode_input_structs(cfg, case)
     pspec = param_specs(params, mesh)
-    cspec = cache_specs(cfg, ins["caches"], mesh, batch=case.batch)
+    cspec = cache_specs(cfg, ins["caches"], mesh, batch=case.batch,
+                        paged=False)
     dp = tuple(a for a in mesh.axis_names if a != "model")
     tok_spec = P(dp, None) if case.batch > 1 else P(None, None)
     args = [params, ins["caches"], ins["token"], ins["index"]]

@@ -7,27 +7,27 @@ Batch mode (legacy lockstep generate):
         --batch 4 --prompt-len 32 --new-tokens 32 --quant w8a8_nibble
 
 Request-level workloads (continuous batching: per-slot positions, slot
-refill, per-request latency), optionally over the paged KV cache and
-with a priority mix:
+refill, per-request latency) over the paged KV cache, with a priority
+mix:
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
         --workload staggered --requests 16 --stagger-ms 50 \
-        --cache-mode paged --page-size 8 --priority-mix 0.25
+        --page-size 8 --priority-mix 0.25
 
 Overcommitted pool (incremental page allocation + evict-and-resume
 preemption: pages are booked per live token, `--num-pages` may sit
 below the sum of worst-case page counts):
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
-        --workload uniform --requests 16 --cache-mode paged \
-        --page-size 8 --alloc-mode incremental --num-pages 24
+        --workload uniform --requests 16 --page-size 8 \
+        --alloc-mode incremental --num-pages 24
 
 Prefix caching (shared system prompt served from refcounted read-only
 pages; only the uncached suffix is prefilled):
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
-        --workload staggered --requests 16 --cache-mode paged \
-        --page-size 8 --prefix-cache --shared-prefix 0.75
+        --workload staggered --requests 16 --page-size 8 \
+        --prefix-cache --shared-prefix 0.75
 
 Self-speculative decoding (the quantized program drafts --spec-k
 tokens, one dense multi-token forward verifies them; greedy streams
@@ -36,7 +36,7 @@ heavy-tail workload:
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
         --workload bursty --requests 16 --stagger-ms 50 \
-        --cache-mode paged --alloc-mode incremental \
+        --alloc-mode incremental \
         --spec-decode --spec-k 4 --spec-quant w8a8_nibble
 
 Tail-latency engineering (chunked prefill and grouped admission through
@@ -46,7 +46,7 @@ workload:
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
         --workload bursty --requests 16 --stagger-ms 50 \
-        --cache-mode paged --alloc-mode incremental --num-pages 24 \
+        --alloc-mode incremental --num-pages 24 \
         --prefill-chunk 8 --admit-group 4 --swap-mode host
 
 Published widths (``get_config(arch)`` unreduced, random weights drawn
@@ -54,8 +54,7 @@ from a seed; sized for an accelerator, not for a CPU):
 
     PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
         --full-width --workload uniform --requests 16 --batch 8 \
-        --prompt-len 1024 --new-tokens 64 --cache-mode paged \
-        --page-size 16
+        --prompt-len 1024 --new-tokens 64 --page-size 16
 
 Compile time is reported separately from steady-state throughput (a
 warmup pass triggers every compilation before the timed run).
@@ -123,9 +122,8 @@ def build(args, *, reference: bool = False, params=None):
     if params is None:
         params = init_params(cfg)
     max_len = args.prompt_len + args.new_tokens
-    if args.cache_mode == "paged":
-        # the paged pool is page-granular; round the budget up
-        max_len += (-max_len) % args.page_size
+    # the pool is page-granular; round the budget up
+    max_len += (-max_len) % args.page_size
     scfg = ServeConfig(batch=args.batch,
                        max_len=max_len,
                        prefill_len=args.prompt_len,
@@ -135,9 +133,8 @@ def build(args, *, reference: bool = False, params=None):
                        alloc_mode=args.alloc_mode,
                        prefix_cache=args.prefix_cache,
                        quant_backend=args.quant_backend,
-                       cache_mode=args.cache_mode,
                        page_size=args.page_size,
-                       num_pages=args.num_pages or None,
+                       num_pages=args.num_pages,
                        spec_decode=args.spec_decode,
                        spec_k=args.spec_k,
                        spec_quant_mode=args.spec_quant,
@@ -195,11 +192,9 @@ def run_workload(args, cfg, engine, collect_streams=False):
                               collect_streams=collect_streams)
 
 
-def _check_leaks(args, engine):
+def _check_leaks(engine):
     """Every page must be back in the allocator once the prefix index
     lets go — a leak here is an engine bug, so fail loudly."""
-    if args.cache_mode != "paged":
-        return
     engine.release_prefix_cache()
     leaked = engine.leaked_pages()
     if leaked:
@@ -223,7 +218,7 @@ def _verify(args, cfg, r):
                          "quantized streams")
     cfg_ref, _, ref = build(args, reference=True)
     ref_r = run_workload(args, cfg_ref, ref, collect_streams=True)
-    _check_leaks(args, ref)
+    _check_leaks(ref)
     if r["streams"] != ref_r["streams"]:
         bad = [i for i in r["streams"]
                if r["streams"][i] != ref_r["streams"][i]]
@@ -314,9 +309,9 @@ def run_requests(args, cfg, engine):
     Poisson bursts at the same mean load with Pareto heavy-tail prompt
     lengths (slots refill mid-stream in all three)."""
     r = run_workload(args, cfg, engine, collect_streams=args.verify)
-    _check_leaks(args, engine)
+    _check_leaks(engine)
     print(f"arch={cfg.name} quant={args.quant} backend={args.quant_backend} "
-          f"cache={args.cache_mode} workload={args.workload} "
+          f"workload={args.workload} "
           f"requests={args.requests} slots={args.batch}")
     print(f"  topology: {r['device_count']} device(s), per-engine mesh "
           f"{tuple(r['mesh_shape'])}, dp_replicas={r['dp_replicas']}")
@@ -339,10 +334,9 @@ def run_requests(args, cfg, engine):
               f"acceptance={r['acceptance_rate']:.0%} "
               f"tokens/step={r['tokens_per_step']:.2f} "
               f"rollback_pages={r['spec_rollback_pages']}")
-    if args.cache_mode == "paged":
-        print(f"  pool: {r['pool_pages']} pages, mean occupancy "
-              f"{r['occupancy']:.0%}, mean concurrency "
-              f"{r['concurrency']:.2f}, preemptions {r['preemptions']}")
+    print(f"  pool: {r['pool_pages']} pages, mean occupancy "
+          f"{r['occupancy']:.0%}, mean concurrency "
+          f"{r['concurrency']:.2f}, preemptions {r['preemptions']}")
     if args.prefix_cache:
         print(f"  prefix cache: hit rate {r['prefix_hit_rate']:.0%} of "
               f"prompt tokens, {r['prefill_tokens']} tokens prefilled")
@@ -409,12 +403,11 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["xla", "pallas"],
                     help="pallas = fused single-pass kernels "
                          "(ops.quant_matmul, in-kernel dequant epilogue)")
-    ap.add_argument("--cache-mode", default="dense",
-                    choices=["dense", "paged"],
-                    help="paged = shared page pools + page-table "
-                         "indirection (cache HBM scales with live tokens)")
+    ap.add_argument("--cache-mode", default="paged", choices=["paged"],
+                    help="the KV layout: shared page pools + page-table "
+                         "indirection, the only one the engine serves")
     ap.add_argument("--page-size", type=int, default=8,
-                    help="tokens per KV page (paged cache mode)")
+                    help="tokens per KV page")
     ap.add_argument("--alloc-mode", default="reserve",
                     choices=["reserve", "incremental"],
                     help="reserve = book worst-case pages at admission; "
@@ -422,12 +415,13 @@ def build_parser() -> argparse.ArgumentParser:
                          "chunk with evict-and-resume preemption "
                          "(allows an overcommitted --num-pages)")
     ap.add_argument("--num-pages", type=int, default=0,
-                    help="KV pool size in pages (0 = parity with the "
-                         "dense slab); set below the worst-case sum to "
-                         "overcommit with --alloc-mode incremental")
+                    help="KV pool size in pages (0 = parity: every "
+                         "slot's max_len at once); set below the "
+                         "worst-case sum to overcommit with --alloc-mode "
+                         "incremental")
     ap.add_argument("--prefix-cache", action="store_true",
                     help="share read-only prompt-prefix pages across "
-                         "requests (paged cache mode): admission maps "
+                         "requests: admission maps "
                          "cached page-aligned chunks and prefills only "
                          "the uncached suffix, copy-on-writing a fully "
                          "covered prompt's tail page")
@@ -459,13 +453,11 @@ def build_parser() -> argparse.ArgumentParser:
                     help="chunked prefill: split every prompt into "
                          "chunks of this many tokens, one chunk per "
                          "scheduler step interleaved with decode "
-                         "chunks (0 = monolithic one-dispatch prefill; "
-                         "paged cache only)")
+                         "chunks (0 = monolithic one-dispatch prefill)")
     ap.add_argument("--admit-group", type=int, default=1,
                     help="grouped admission: up to this many prefilling "
                          "requests advance per wave as one padded "
-                         "batch through the single wave program "
-                         "(paged cache only)")
+                         "batch through the single wave program")
     ap.add_argument("--swap-mode", default="off",
                     choices=["off", "host"],
                     help="host = on eviction copy the victim's live KV "

@@ -9,7 +9,6 @@ from repro.models.transformer import (  # noqa: F401
     init_caches,
     init_paged_caches,
     insert_cache_pages,
-    merge_slot_caches,
     merge_slot_paged_caches,
     model_init,
     prefill,

@@ -145,8 +145,8 @@ def gather_pages(pool, table):
 
     (num_pages, page_size, ...) gathered through (B, max_pages) →
     (B, max_pages · page_size, ...): the XLA reference decode path —
-    after the gather the attention math is bit-identical to the dense
-    slab (rows beyond a slot's live length hold garbage from the trash
+    after the gather the attention math is bit-identical to the unpaged
+    cache (rows beyond a slot's live length hold garbage from the trash
     page or stale pages, exactly where the causal mask already writes
     ``-inf``).
     """
@@ -350,7 +350,7 @@ def attn_apply(params, cfg, x, *, positions, kind: str = "full",
       ``cache`` leaves are shared (num_pages, page_size, ...) pools; the
       scatter routes through the table and attention either gathers
       pages back into position order (XLA reference path, bit-identical
-      to the dense slab) or, under ``attn_impl="flash"``, runs the
+      to the unpaged cache) or, under ``attn_impl="flash"``, runs the
       Pallas paged-decode kernel that walks the table directly.
     * context prefill (prefix caching): ``cache`` + ``page_table`` +
       ``context_start`` — ``x`` holds a prompt *suffix* whose queries
@@ -421,7 +421,7 @@ def attn_apply(params, cfg, x, *, positions, kind: str = "full",
     elif cache is not None and page_table is not None:
         # paged decode: scatter through the page table into the shared
         # pool, then either gather pages back into position order (XLA
-        # reference — bit-identical to the dense slab) or let the Pallas
+        # reference — bit-identical to the unpaged cache) or let the Pallas
         # flash-decode kernel walk the table (fast path, no gather copy)
         quant_kv = "k_scale" in cache
         if quant_kv:

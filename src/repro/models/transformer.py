@@ -52,8 +52,8 @@ from repro.models.mamba import (
 from repro.models.moe import moe_apply, moe_init
 
 __all__ = ["model_init", "forward", "prefill", "decode_step", "init_caches",
-           "init_paged_caches", "merge_slot_caches",
-           "merge_slot_paged_caches", "scatter_prefill_paged_caches",
+           "init_paged_caches", "merge_slot_paged_caches",
+           "scatter_prefill_paged_caches",
            "copy_paged_cache_page", "extract_cache_pages",
            "insert_cache_pages", "encode", "unrolled_blocks"]
 
@@ -239,7 +239,7 @@ def _stack_apply(params, cfg: ModelConfig, x, *, positions, caches=None,
     # step.  Paged pools are viewed as one pool of n_blocks × num_pages
     # pages, and a layer addresses its own through the page table
     # shifted by layer × num_pages, so its rows are written and gathered
-    # straight in the stack.  Other leaves (dense slabs, mamba state)
+    # straight in the stack.  Other leaves (unpaged caches, mamba state)
     # read and write their layer's entry by dynamic index.  A fresh
     # prefill (no cache given) emits its new per-layer caches as ys.
     if cfg.n_blocks:
@@ -398,22 +398,11 @@ def _cache_tree(cfg: ModelConfig, layer_cache) -> dict:
 
 
 def init_caches(cfg: ModelConfig, batch: int, max_len: int) -> dict:
-    """Decode caches: dense per-slot slabs, or — when
-    ``cfg.cache_mode == "paged"`` — shared page pools (see
-    :func:`init_paged_caches`) addressed through a page table.  Mamba
-    recurrent state has no sequence axis and stays per-slot either way.
-    """
-    if cfg.cache_mode == "paged":
-        page_size = cfg.page_size
-        if page_size < 1:
-            raise ValueError(f"page_size must be >= 1, got {page_size}")
-        if max_len % page_size:
-            raise ValueError(f"max_len {max_len} must be a multiple of "
-                             f"page_size {page_size}")
-        # auto pool: capacity parity with the dense slab + trash page
-        num_pages = cfg.num_pages or batch * (max_len // page_size) + 1
-        return init_paged_caches(cfg, batch, num_pages, page_size)
-
+    """Unpaged decode caches: one ``(batch, max_len, ...)`` row per
+    sequence — the layout a prefill emits and a plain ``decode_step``
+    (no page table) reads.  A serving engine stores KV in page pools
+    instead (:func:`init_paged_caches`).  Mamba recurrent state has no
+    sequence axis and is per-sequence in both."""
     def layer_cache(spec: LayerSpec):
         if spec.mixer == "attn":
             if spec.attn_kind == "mla":
@@ -431,8 +420,9 @@ def init_paged_caches(cfg: ModelConfig, batch: int, num_pages: int,
     """Paged dual of :func:`init_caches`: every attention/MLA leaf is a
     shared ``(num_pages, page_size, ...)`` pool (one pool per layer; the
     scanned blocks stack pools on their leading block axis exactly like
-    the dense slabs).  Capacity scales with *live* tokens: ``num_pages``
-    is a workload knob, not ``batch × max_len / page_size``."""
+    the unpaged caches).  Capacity scales with *live* tokens:
+    ``num_pages`` is a workload knob, not ``batch × max_len /
+    page_size``."""
     def layer_cache(spec: LayerSpec):
         if spec.mixer == "attn":
             if spec.attn_kind == "mla":
@@ -550,32 +540,17 @@ def _grow_caches(cfg, caches, cur_len, max_len):
     return jax.tree_util.tree_map_with_path(pad_leaf, caches)
 
 
-def merge_slot_caches(big, one, slot):
-    """Scatter a single-sequence cache tree into slot ``slot`` of a
-    batched cache tree (same max_len; ``one`` has batch 1 where ``big``
-    has batch B).  The batch axis is found structurally: axis 0 for
-    prefix/suffix leaves, axis 1 under the block-stack leading axis."""
-    def put(path, b_leaf, s_leaf):
-        b_ax = 1 if _is_block_leaf(path) else 0
-        start = [0] * b_leaf.ndim
-        start[b_ax] = slot
-        return jax.lax.dynamic_update_slice(
-            b_leaf, s_leaf.astype(b_leaf.dtype), tuple(start))
-
-    return jax.tree_util.tree_map_with_path(put, big, one)
-
-
 def merge_slot_paged_caches(big, one, slot, pages):
-    """Paged dual of :func:`merge_slot_caches`: copy a prefilled
-    single-sequence cache into the shared page pools instead of a slab
-    row.  ``one``'s sequence leaves (length ``S``, a multiple of the
+    """Copy a prefilled single-sequence cache into the shared page
+    pools.  ``one``'s sequence leaves (length ``S``, a multiple of the
     pool page size) are reshaped into ``S / page_size`` whole pages and
     scattered to the page ids in ``pages`` (a ``(max_pages,)`` traced
     vector — entries past the request's live pages point at the trash
     page, so pad-token pages land somewhere harmless and one
     compilation serves every prompt length).  Non-sequence leaves
-    (mamba conv/ssm state) scatter at batch slot ``slot`` exactly as in
-    the dense path."""
+    (mamba conv/ssm state) scatter at batch slot ``slot``; the batch
+    axis is found structurally: axis 0 for prefix/suffix leaves, axis 1
+    under the block-stack leading axis."""
     pages = jnp.asarray(pages, jnp.int32)
 
     def put(path, b_leaf, s_leaf):

@@ -2,7 +2,6 @@ from repro.serve.engine import (  # noqa: F401
     Engine,
     Request,
     ServeConfig,
-    make_serve_step,
 )
 from repro.serve.paging import (  # noqa: F401
     PageAllocator,

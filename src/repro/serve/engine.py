@@ -9,15 +9,16 @@ program.  Concretely:
   position vector, so every slot decodes at its own offset — positions
   are data, not shape, and one compilation serves every mix of request
   lengths.
-* **Paged KV cache.**  With ``cache_mode="paged"`` the per-slot
-  ``max_len`` slab is replaced by shared ``(num_pages, page_size, ...)``
-  pools plus a ``(batch, max_pages)`` int32 page table — the paper's
-  fixed-width-reusable-unit idea applied to KV storage.  Page ids are
-  data, not shape, so allocation, refill and recycling never recompile;
-  a host-side free-list allocator (``serve.paging``) hands pages out at
-  admission and takes them back at completion, and admission *defers*
-  (backpressure) instead of OOMing when the pool is exhausted.  Cache
-  HBM then scales with live tokens, not ``batch × max_len``.
+* **Paged KV cache.**  The engine keeps KV in shared ``(num_pages,
+  page_size, ...)`` pools plus a ``(batch, max_pages)`` int32 page
+  table — the paper's fixed-width-reusable-unit idea applied to KV
+  storage — and owns that geometry (``ServeConfig.page_size`` /
+  ``num_pages``).  Page ids are data, not shape, so allocation, refill
+  and recycling never recompile; a host-side free-list allocator
+  (``serve.paging``) hands pages out at admission and takes them back
+  at completion, and admission *defers* (backpressure) instead of
+  OOMing when the pool is exhausted.  Cache HBM then scales with live
+  tokens, not ``batch × max_len``.
 * **Incremental page allocation.**  ``alloc_mode="reserve"`` books a
   request's worst-case page count up front; ``alloc_mode="incremental"``
   books only the prompt pages (plus the first decode page) and tops a
@@ -38,13 +39,11 @@ program.  Concretely:
   priority as admission, so equal-priority requests never ping-pong.
 * **Prefill into a free slot.**  A new request is prefilled alone
   (batch 1), padded to the slot prompt budget (``prefill_len``), and its
-  caches are scattered into the free slot of the shared batched cache
-  (``merge_slot_caches``; the paged dual copies whole prompt *pages*
-  into the pools instead of padding a dense slab to ``max_len``).
-  Pad-token cache rows are harmless: decode overwrites row ``p`` before
-  any query can attend to it.
+  whole prompt *pages* are copied into the slot's pool pages
+  (``merge_slot_paged_caches``).  Pad-token cache rows are harmless:
+  decode overwrites row ``p`` before any query can attend to it.
 * **Prefix caching (copy-on-write pages).**  With
-  ``prefix_cache=True`` (paged mode only) the engine keeps a host-side
+  ``prefix_cache=True`` the engine keeps a host-side
   index from page-aligned prompt-chunk hashes to the pool pages holding
   their KV rows (``serve.paging.PrefixCache``).  Admission maps the
   longest cached prefix into the new slot's page-table row *read-only*
@@ -151,9 +150,6 @@ across preemption (the draft model's cache after resume differs from
 the uninterrupted run's, which can shift acceptance boundaries —
 greedy spec streams stay bit-identical; ``swap_mode="host"`` removes
 the replay, and with it this caveat, whenever the host tier has room).
-
-``make_serve_step`` remains the single-token jit-able step the decode
-dry-run cells lower.
 """
 
 from __future__ import annotations
@@ -173,9 +169,8 @@ from repro.models import (
     copy_paged_cache_page,
     decode_step,
     extract_cache_pages,
-    init_caches,
+    init_paged_caches,
     insert_cache_pages,
-    merge_slot_caches,
     merge_slot_paged_caches,
     prefill,
     scatter_prefill_paged_caches,
@@ -204,7 +199,7 @@ from repro.serve.spans import (
     step_span,
 )
 
-__all__ = ["ServeConfig", "Request", "make_serve_step", "Engine"]
+__all__ = ["ServeConfig", "Request", "Engine"]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -225,23 +220,17 @@ class ServeConfig:
     #   gates preemption, so a long-waiting request climbs toward
     #   admission and, once admitted, becomes correspondingly harder to
     #   evict — equal-priority requests can never evict each other.
-    alloc_mode: str = "reserve"       # paged-mode page accounting:
+    alloc_mode: str = "reserve"       # page accounting:
     #   "reserve" books every request's worst-case page count at
     #   admission; "incremental" books only the prompt pages (plus the
     #   first decode page) and tops slots up per decode chunk,
     #   preempting the weakest runner when the pool runs dry — the same
     #   pool then sustains more concurrent requests (overcommit).
-    # Serving-time overrides: deploy any checkpoint under a different
-    # execution mode/backend/cache layout than it was configured with
-    # (the params stay bf16; integer modes quantize on the fly).
-    # ``None`` keeps the model config's setting.
-    # ``quant_backend="pallas"`` routes every projection through
-    # ``ops.quant_matmul`` — the single-pass plane-fused kernel with the
-    # in-kernel dequant epilogue.  ``cache_mode="paged"`` switches the
-    # KV cache to page pools + page-table indirection; ``page_size`` /
-    # ``num_pages`` size the pool (num_pages=0 → capacity parity with
-    # the dense slab).
-    prefix_cache: bool = False        # paged mode only: share read-only
+    page_size: int = 16               # tokens per KV pool page
+    num_pages: int = 0                # pool size in pages, the trash page
+    #   included; 0 = capacity parity: batch × max_len / page_size + 1,
+    #   every slot's worst case at once (shrink it to bank the HBM win)
+    prefix_cache: bool = False        # share read-only
     #   prompt-prefix pages across requests (hash-indexed page-aligned
     #   chunks, refcounted pages, copy-on-write on a fully covered
     #   prompt's tail page).  Admission prefills only the uncached
@@ -250,11 +239,14 @@ class ServeConfig:
     #   mamba-mixer models (recurrent state cannot compose with a
     #   cached prefix) and the int8 KV cache (cached rows would be
     #   dequantized where a solo prefill attends full precision).
+    # Serving-time overrides: deploy any checkpoint under a different
+    # execution mode/backend than it was configured with (the params
+    # stay bf16; integer modes quantize on the fly).  ``None`` keeps the
+    # model config's setting.  ``quant_backend="pallas"`` routes every
+    # projection through ``ops.quant_matmul`` — the single-pass
+    # plane-fused kernel with the in-kernel dequant epilogue.
     quant_mode: str | None = None
     quant_backend: str | None = None
-    cache_mode: str | None = None
-    page_size: int | None = None
-    num_pages: int | None = None
     spec_decode: bool = False         # self-speculative decoding: the
     #   quantized (nibble) program drafts ``spec_k`` tokens per slot,
     #   then ONE multi-token dense forward verifies all draft positions
@@ -277,16 +269,16 @@ class ServeConfig:
     #   prompt bounds other slots' ITL impact to one chunk's latency).
     #   0 keeps the classic monolithic one-dispatch prefill — unless
     #   ``admit_group > 1``, which also enables the wave program with
-    #   chunk width ``prefill_len``.  Paged cache only; incompatible
-    #   with mamba mixers (chunk boundaries are cache positions, not
-    #   recurrent state) and the int8 KV cache.
+    #   chunk width ``prefill_len``.  Incompatible with mamba mixers
+    #   (chunk boundaries are cache positions, not recurrent state) and
+    #   the int8 KV cache.
     admit_group: int = 1              # grouped admission: up to this
     #   many prefilling slots advance per wave as one padded (G, chunk)
     #   batch — a simultaneous burst costs one program launch instead of
     #   G serialized batch-1 prefills.  The group budget is fixed, so
-    #   the wave program compiles exactly once.  > 1 requires the paged
-    #   cache and (when ``prefill_chunk`` is 0) a ``prefill_len`` budget
-    #   to serve as the wave width.
+    #   the wave program compiles exactly once.  > 1 requires (when
+    #   ``prefill_chunk`` is 0) a ``prefill_len`` budget to serve as
+    #   the wave width.
     swap_mode: str = "off"            # "host": on eviction copy the
     #   victim's live KV pages to a host-memory cold pool
     #   (``HostPagePool``) and restore them on resume — preemption
@@ -296,8 +288,8 @@ class ServeConfig:
     #   gives the prefix cache a cold tier: reclaimed entries demote to
     #   host pages and promote back on a later hit.  A full host pool
     #   falls back to replay-resume.  "off" keeps replay-only resume.
-    #   Paged cache only; incompatible with mamba mixers (recurrent
-    #   state is per-slot, not paged — a restore cannot rebuild it).
+    #   Incompatible with mamba mixers (recurrent state is per-slot,
+    #   not paged — a restore cannot rebuild it).
     host_pages: int = 0               # host cold-pool capacity in pages
     #   for ``swap_mode="host"``; 0 = twice the device pool's
     #   allocatable capacity (host RAM is the bigger tier by design).
@@ -310,7 +302,7 @@ class ServeConfig:
     #   slot finishes.  0 = uncapped (pressure-driven reclaim only).
     tp: int = 1                       # tensor-parallel width: shard the
     #   weights (param_specs rules) and the paged KV/scale pools'
-    #   KV-head dimension (cache_specs paged rules; in-page sequence
+    #   KV-head dimension (cache_specs pool rules; in-page sequence
     #   fallback when heads don't divide) over the mesh's "model" axis,
     #   and build every compiled program with explicit in/out shardings
     #   under a (1, tp) local mesh.  The page table stays host-side and
@@ -341,8 +333,8 @@ class Request:
     #   stamps) — consecutive diffs are the inter-token latencies the
     #   workload driver aggregates into ITL percentiles
     cache_rows: int = 0               # peak cache rows reserved for this
-    #   request: max_len in dense mode, pages × page_size in paged mode
-    #   (the per-request HBM footprint the benchmark reports)
+    #   request: pages × page_size (the per-request HBM footprint the
+    #   benchmark reports)
     truncated: bool = False           # max_new_tokens was cut to fit the
     #   max_len budget at submit (explicit, so short output is never
     #   misread as an early EOS)
@@ -542,8 +534,7 @@ class _CountingJit:
 
 def _apply_overrides(cfg: ModelConfig, scfg: ServeConfig) -> ModelConfig:
     updates = {}
-    for field in ("quant_mode", "quant_backend", "cache_mode", "page_size",
-                  "num_pages"):
+    for field in ("quant_mode", "quant_backend"):
         val = getattr(scfg, field)
         if val is not None:
             updates[field] = val
@@ -599,35 +590,11 @@ _TAG_RESAMPLE = 2
 _TAG_DRAFT = 3
 
 
-def make_serve_step(cfg: ModelConfig, scfg: ServeConfig) -> Callable:
-    """serve_step(params, caches, token, index, rng) → (next_token, caches).
-
-    ``index`` is a traced scalar *or* ``(B,)`` per-slot position vector —
-    one compilation serves every decode position assignment.  Greedy or
-    temperature sampling on-device.  Dense caches only: the paged layout
-    needs a page table threaded per step, which this single-token
-    dry-run entry point does not carry — use ``Engine`` for paged mode.
-    """
-    cfg = _apply_overrides(cfg, scfg)
-    if cfg.cache_mode == "paged":
-        raise ValueError("make_serve_step does not support "
-                         "cache_mode='paged' (no page-table plumbing); "
-                         "use Engine for the paged cache")
-    sample = _sampler(scfg)
-
-    def serve_step(params, caches, token, index, rng):
-        logits, caches = decode_step(params, cfg, token, caches, index)
-        nxt = sample(logits[:, -1], rng)
-        return nxt[:, None], caches
-
-    return serve_step
-
-
 class Engine:
     """Continuous-batching engine: priority request queue + slot refill +
-    chunked jitted decode, over a dense or paged KV cache, with
-    incremental page allocation, evict-and-resume preemption and
-    refcounted prefix caching (copy-on-write pages) in paged mode.  See
+    chunked jitted decode over a paged KV cache, with incremental page
+    allocation, evict-and-resume preemption and refcounted prefix
+    caching (copy-on-write pages).  See
     the module docstring for the execution model and ``docs/serving.md``
     for the operator-facing reference."""
 
@@ -648,35 +615,20 @@ class Engine:
         specs = (*cfg.prefix_pattern, *cfg.block_pattern,
                  *cfg.suffix_pattern)
         self._has_mamba = any(s.mixer == "mamba" for s in specs)
-        self._paged = self.cfg.cache_mode == "paged"
         self._incremental = scfg.alloc_mode == "incremental"
-        if self._incremental and not self._paged:
-            raise ValueError("alloc_mode='incremental' requires "
-                             "cache_mode='paged' (the dense slab has no "
-                             "pages to grow)")
-        if self._paged:
-            ps = self.cfg.page_size
-            if ps < 1:
-                raise ValueError(f"page_size must be >= 1, got {ps}")
-            if scfg.max_len % ps:
-                raise ValueError(f"max_len {scfg.max_len} must be a "
-                                 f"multiple of page_size {ps}")
-            self._page_size = ps
-            self._max_pages = scfg.max_len // ps
-            self._num_pages = (self.cfg.num_pages
-                               or scfg.batch * self._max_pages + 1)
-            # page 0 is the trash page: idle slots' table rows point at
-            # it so their frozen idempotent cache writes never corrupt a
-            # recycled page
-            self.cfg = self.cfg.replace(num_pages=self._num_pages)
-        elif self.cfg.cache_mode != "dense":
-            raise ValueError(f"cache_mode must be 'dense' or 'paged', "
-                             f"got {self.cfg.cache_mode!r}")
+        ps = scfg.page_size
+        if ps < 1:
+            raise ValueError(f"page_size must be >= 1, got {ps}")
+        if scfg.max_len % ps:
+            raise ValueError(f"max_len {scfg.max_len} must be a "
+                             f"multiple of page_size {ps}")
+        self._page_size = ps
+        self._max_pages = scfg.max_len // ps
+        # page 0 is the trash page: idle slots' table rows point at it so
+        # their frozen idempotent cache writes never corrupt a recycled
+        # page
+        self._num_pages = scfg.num_pages or scfg.batch * self._max_pages + 1
         if scfg.prefix_cache:
-            if not self._paged:
-                raise ValueError("prefix_cache=True requires "
-                                 "cache_mode='paged' (the dense slab has "
-                                 "no pages to share)")
             if self._has_mamba:
                 raise ValueError("prefix_cache=True is incompatible with "
                                  "mamba-mixer models: SSM state is "
@@ -726,11 +678,6 @@ class Engine:
         # compile count is 0 (see ``compile_counts``)
         self._wave = scfg.prefill_chunk > 0 or scfg.admit_group > 1
         if self._wave:
-            if not self._paged:
-                raise ValueError("prefill_chunk/admit_group require "
-                                 "cache_mode='paged': the wave program "
-                                 "writes prompt rows through page-table "
-                                 "rows, not a dense slab")
             if self._has_mamba:
                 raise ValueError("prefill_chunk/admit_group are "
                                  "incompatible with mamba-mixer models: "
@@ -750,16 +697,11 @@ class Engine:
                                  "wave width")
             self._wave_group = scfg.admit_group
         self._swap = scfg.swap_mode == "host"
-        if self._swap:
-            if not self._paged:
-                raise ValueError("swap_mode='host' requires "
-                                 "cache_mode='paged': the dense slab "
-                                 "has no pages to swap")
-            if self._has_mamba:
-                raise ValueError("swap_mode='host' is incompatible with "
-                                 "mamba-mixer models: recurrent state "
-                                 "is per-slot, not paged, so a page "
-                                 "restore cannot rebuild it")
+        if self._swap and self._has_mamba:
+            raise ValueError("swap_mode='host' is incompatible with "
+                             "mamba-mixer models: recurrent state is "
+                             "per-slot, not paged, so a page restore "
+                             "cannot rebuild it")
         # TP mesh: built before the compiled stages so their explicit
         # in/out shardings can reference the sharded param/cache trees
         # (None = no mesh, the single-device engine — every jit is then
@@ -773,7 +715,8 @@ class Engine:
         device = (list(devices)[0] if self._mesh is None
                   and devices is not None else None)
         with jax.default_device(device):
-            self._caches = init_caches(self.cfg, scfg.batch, scfg.max_len)
+            self._caches = init_paged_caches(self.cfg, scfg.batch,
+                                             self._num_pages, ps)
         # where params and caches are committed: the mesh, that one
         # device, or None = JAX's default device
         self._cache_sh = None
@@ -784,7 +727,7 @@ class Engine:
             self._cache_sh = SingleDeviceSharding(device)
             self.params = jax.device_put(self.params, self._cache_sh)
             self._caches = jax.device_put(self._caches, self._cache_sh)
-        # the cache slab/pool is donated: both stages rebind it from the
+        # the cache pool is donated: every stage rebinds it from the
         # return value, so the update happens in place instead of
         # copying every unmodified row (the out_shardings under a mesh
         # match the donated input's, so donation still applies)
@@ -842,12 +785,12 @@ class Engine:
         return make_local_mesh(dp=shape[0], tp=shape[1], devices=devices)
 
     def _shard_state(self):
-        """Commit the params and the cache slab/pools to the mesh with
-        the repo's partition rules: weights via ``param_specs``
-        (megatron col/row TP pairs), caches via ``cache_specs`` (paged
-        branch: KV heads on "model" when divisible, in-page sequence
-        axis otherwise; the page table ships replicated with every
-        dispatch — see ``distributed.sharding.page_table_spec``)."""
+        """Commit the params and the cache pools to the mesh with the
+        repo's partition rules: weights via ``param_specs`` (megatron
+        col/row TP pairs), caches via ``cache_specs`` (pool rules: KV
+        heads on "model" when divisible, in-page sequence axis
+        otherwise; the page table ships replicated with every dispatch
+        — see ``distributed.sharding.page_table_spec``)."""
         from jax.sharding import NamedSharding
         from jax.sharding import PartitionSpec as P
 
@@ -861,7 +804,7 @@ class Engine:
         self._param_sh = to_shardings(param_specs(self.params, self._mesh))
         self._cache_sh = to_shardings(
             cache_specs(self.cfg, self._caches, self._mesh,
-                        batch=self.scfg.batch))
+                        batch=self.scfg.batch, paged=True))
         self._repl = NamedSharding(self._mesh, P())
         self.params = jax.device_put(self.params, self._param_sh)
         self._caches = jax.device_put(self._caches, self._cache_sh)
@@ -907,18 +850,15 @@ class Engine:
 
     def _prefill_pad_len(self, pad_len: int) -> int:
         """Cache length the prefill stage grows to: the prompt budget,
-        rounded up to whole pages in paged mode (the page merge copies
-        whole pages; rows past the real prompt are pad garbage that
-        decode overwrites or the causal mask hides)."""
-        if not self._paged:
-            return self.scfg.max_len
+        rounded up to whole pages (the page merge copies whole pages;
+        rows past the real prompt are pad garbage that decode overwrites
+        or the causal mask hides)."""
         ps = self._page_size
         return -(-pad_len // ps) * ps
 
     def _build_prefill(self):
         cfg, scfg = self.cfg, self.scfg
         sample = _sampler(scfg)
-        paged = self._paged
         if scfg.prefix_cache:
             return self._build_prefix_prefill()
 
@@ -926,15 +866,12 @@ class Engine:
                               pages, rng):
             """prompt: (1, P) — padded; prompt_len/slot: traced scalars;
             pages: (max_pages,) traced page-id row (trash-filled past the
-            request's live pages; ignored in dense mode)."""
+            request's live pages)."""
             grow_to = self._prefill_pad_len(prompt.shape[1])
             logits, one, _ = prefill(params, cfg, prompt,
                                      max_len=grow_to,
                                      logits_index=prompt_len - 1)
-            if paged:
-                caches = merge_slot_paged_caches(caches, one, slot, pages)
-            else:
-                caches = merge_slot_caches(caches, one, slot)
+            caches = merge_slot_paged_caches(caches, one, slot, pages)
             first = sample(logits[:, -1], rng)[0]
             return caches, first
 
@@ -1010,14 +947,13 @@ class Engine:
         cfg, scfg = self.cfg, self.scfg
         sample = _slot_sampler(scfg)
         max_pos = scfg.max_len - 1
-        paged = self._paged
 
         def chunk(params, caches, token, positions, active, remaining,
                   table, forced, forced_on, keys, counts):
             """Scan ``decode_chunk`` tokens; inactive slots are frozen
-            (their rewrites land on already-written rows — or, paged, on
-            the trash page) and emit -1.  ``table`` is the (B, max_pages)
-            page table (all-trash dummy in dense mode).  ``forced`` /
+            (their rewrites land on already-written rows or on the trash
+            page) and emit -1.  ``table`` is the (B, max_pages) page
+            table.  ``forced`` /
             ``forced_on`` are (decode_chunk, B) teacher-forcing lanes:
             where ``forced_on`` a preempted request's stored token
             replaces the sampled one, replaying its stream verbatim so
@@ -1028,14 +964,11 @@ class Engine:
             discarded and fresh draws after a resume land on exactly
             the keys an uninterrupted run would have used — sampled
             streams are bit-stable under preemption."""
-            page_table = table if paged else None
-
             def body(carry, xs):
                 f_tok, f_on = xs
                 caches, token, positions, active, remaining, counts = carry
                 logits, caches = decode_step(params, cfg, token, caches,
-                                             positions,
-                                             page_table=page_table)
+                                             positions, page_table=table)
                 nxt = sample(logits[:, -1], _fold_counts(keys, counts))
                 nxt = jnp.where(f_on, f_tok, nxt)
                 emitted = jnp.where(active, nxt, -1)
@@ -1071,7 +1004,6 @@ class Engine:
         k = scfg.spec_k
         temp = scfg.temperature
         max_pos = scfg.max_len - 1
-        paged = self._paged
 
         def draft(params, caches, token, positions, active, table,
                   forced, forced_on, keys, counts):
@@ -1080,14 +1012,11 @@ class Engine:
             request's committed tokens are re-proposed verbatim and
             force-accepted in verify); keys/counts: per-slot stream
             keys + the stream index of each slot's first draft."""
-            page_table = table if paged else None
-
             def body(carry, xs):
                 f_tok, f_on = xs
                 caches, token, positions, counts = carry
                 logits, caches = decode_step(params, cfg, token, caches,
-                                             positions,
-                                             page_table=page_table)
+                                             positions, page_table=table)
                 lg = logits[:, -1].astype(jnp.float32)
                 if temp > 0.0:
                     probs = jax.nn.softmax(lg / temp, axis=-1)
@@ -1137,7 +1066,6 @@ class Engine:
         cfg, scfg = self.cfg, self.scfg
         k = scfg.spec_k
         temp = scfg.temperature
-        paged = self._paged
 
         def verify(params, caches, token, drafts, start, table,
                    forced_on, dprobs, keys, counts):
@@ -1145,10 +1073,9 @@ class Engine:
             start: (B,) row of ``token``; forced_on: (k, B);
             dprobs: (k, B, V) draft distributions ((k, B, 1) dummy in
             greedy mode); counts: stream index of ``drafts[0]``."""
-            page_table = table if paged else None
             tokens = jnp.concatenate([token, drafts.T], axis=1)  # (B,k+1)
             logits, caches = decode_step(params, cfg, tokens, caches,
-                                         start, page_table=page_table)
+                                         start, page_table=table)
             lg = logits.astype(jnp.float32)                   # (B,k+1,V)
             d = tokens[:, 1:]                                 # (B, k)
             f_on = forced_on.T                                # (B, k)
@@ -1261,40 +1188,30 @@ class Engine:
         self._slot_cow: list[tuple[int, int]] = [(0, 0)] * b
         self.prefix_cache: PrefixCache | None = None
         self.host_pool: HostPagePool | None = None
-        if self._paged:
-            self.allocator = PageAllocator(self._num_pages, reserved=1)
-            self.page_table = PageTable(b, self._max_pages, trash_page=0,
-                                        num_pages=self._num_pages,
-                                        reserved=1)
-            self._slot_pages: list[list[int] | None] = [None] * b
-            if self._swap:
-                self.host_pool = HostPagePool(
-                    self.scfg.host_pages or 2 * self.allocator.capacity)
-                # every host-tier extract/insert pads its page vector to
-                # one fixed width (the per-slot maximum), so the eager
-                # gather/scatter pair compiles exactly one shape — and
-                # that compile is pre-paid here, on a trash-page
-                # round-trip, instead of inside the serving loop at the
-                # first preemption
-                self._swap_pad = self._max_pages
-                warm = extract_cache_pages(self._caches, [0],
-                                           pad_to=self._swap_pad)
-                self._caches = insert_cache_pages(self._caches, [0], warm,
-                                                  pad_to=self._swap_pad)
-                if self._cache_sh is not None:
-                    self._caches = jax.device_put(self._caches,
-                                                  self._cache_sh)
-            if self.scfg.prefix_cache:
-                self.prefix_cache = PrefixCache(self._page_size,
-                                                self.allocator)
-                if self.host_pool is not None:
-                    self.prefix_cache.attach_cold_tier(
-                        self._demote_page,
-                        lambda hid: self.host_pool.free([hid]))
-        else:
-            # dense mode ships an all-zero dummy table so the chunk
-            # signature (and its single compilation) is layout-invariant
-            self.page_table = PageTable(b, 1, trash_page=0)
+        self.allocator = PageAllocator(self._num_pages, reserved=1)
+        self.page_table = PageTable(b, self._max_pages, trash_page=0,
+                                    num_pages=self._num_pages, reserved=1)
+        self._slot_pages: list[list[int] | None] = [None] * b
+        if self._swap:
+            self.host_pool = HostPagePool(
+                self.scfg.host_pages or 2 * self.allocator.capacity)
+            # every host-tier extract/insert pads its page vector to one
+            # fixed width (the per-slot maximum), so the eager
+            # gather/scatter pair compiles exactly one shape — and that
+            # compile is pre-paid here, on a trash-page round-trip,
+            # instead of inside the serving loop at the first preemption
+            self._swap_pad = self._max_pages
+            warm = extract_cache_pages(self._caches, [0],
+                                       pad_to=self._swap_pad)
+            self._caches = insert_cache_pages(self._caches, [0], warm,
+                                              pad_to=self._swap_pad)
+            if self._cache_sh is not None:
+                self._caches = jax.device_put(self._caches, self._cache_sh)
+        if self.scfg.prefix_cache:
+            self.prefix_cache = PrefixCache(self._page_size, self.allocator)
+            if self.host_pool is not None:
+                self.prefix_cache.attach_cold_tier(
+                    self._demote_page, lambda hid: self.host_pool.free([hid]))
 
     @property
     def compile_counts(self) -> dict:
@@ -1351,16 +1268,14 @@ class Engine:
         """Scheduling counters for the run since the last ``reset``:
         ``preemptions`` (evict-and-resume events), ``occupancy`` (mean
         fraction of allocatable pool pages in use, sampled at each
-        decode chunk; 0 in dense mode), ``concurrency`` (mean admitted
-        requests per chunk) and ``pool_pages`` (device pool size)."""
+        decode chunk), ``concurrency`` (mean admitted requests per
+        chunk) and ``pool_pages`` (device pool size)."""
         n = max(1, self._stat_samples)
-        occ = (self._stat_in_use / (n * self.allocator.capacity)
-               if self._paged else 0.0)
         return {"preemptions": self.preemptions,
-                "occupancy": occ,
+                "occupancy": (self._stat_in_use
+                              / (n * self.allocator.capacity)),
                 "concurrency": self._stat_running / n,
-                "pool_pages": self.allocator.num_pages if self._paged
-                else 0,
+                "pool_pages": self.allocator.num_pages,
                 # prefix-cache counters (zero / empty without the cache):
                 # hit_rate = prompt tokens served from cached pages over
                 # all prompt tokens admitted; prefill_tokens = real
@@ -1421,8 +1336,7 @@ class Engine:
         """KV-cache bytes per cached token, summed over every layer's
         sequence-axis leaves (scales and block stacking included) —
         multiply by a request's ``cache_rows`` for its HBM footprint."""
-        rows = (self._num_pages * self._page_size if self._paged
-                else self.scfg.batch * self.scfg.max_len)
+        rows = self._num_pages * self._page_size
         total = 0
         for path, leaf in jax.tree_util.tree_flatten_with_path(
                 self._caches)[0]:
@@ -1523,14 +1437,12 @@ class Engine:
         budget = scfg.max_len - prompt.size
         truncated = max_new_tokens > budget
         clamped = min(max_new_tokens, budget)
-        if self._paged:
-            rows = prompt.size + clamped - 1
-            need = pages_needed(rows, self._page_size)
-            if need > self.allocator.capacity:
-                raise ValueError(
-                    f"request needs {need} pages but the pool capacity "
-                    f"is {self.allocator.capacity}; raise num_pages or "
-                    f"shorten the request")
+        need = pages_needed(prompt.size + clamped - 1, self._page_size)
+        if need > self.allocator.capacity:
+            raise ValueError(
+                f"request needs {need} pages but the pool capacity is "
+                f"{self.allocator.capacity}; raise num_pages or shorten "
+                f"the request")
         return prompt, clamped, truncated
 
     def submit(self, prompt, max_new_tokens: int, arrival: float = 0.0,
@@ -1595,13 +1507,11 @@ class Engine:
         return booked - len(shared)
 
     def _can_admit(self, req: Request) -> bool:
-        """Admission backpressure: in paged mode the pool must cover the
-        request's booked pages (freed pages un-defer it later).  With
-        the prefix cache, cached pages do not need allocating, and cold
-        index entries are reclaimed (LRU, never this plan's own hits)
-        before deferring."""
-        if not self._paged:
-            return True
+        """Admission backpressure: the pool must cover the request's
+        booked pages (freed pages un-defer it later).  With the prefix
+        cache, cached pages do not need allocating, and cold index
+        entries are reclaimed (LRU, never this plan's own hits) before
+        deferring."""
         need = self._admission_pages(req)
         if self.allocator.can_alloc(need):
             return True
@@ -1672,7 +1582,7 @@ class Engine:
                 req.swap_pages = hids
                 req.swap_rows = rows
                 self.swap_outs += 1
-        if self._paged and self._slot_pages[slot] is not None:
+        if self._slot_pages[slot] is not None:
             self.allocator.free(self._slot_pages[slot])
             self._slot_pages[slot] = None
             self.page_table.clear(slot)
@@ -1733,8 +1643,8 @@ class Engine:
                 # all slots busy: evict for the slot only if the
                 # arrival's pages are also coverable, else the victim
                 # would lose its slot to an inadmissible head-of-queue
-                if self._paged and (self._evictable_pages(now, cutoff)
-                                    < self._admission_pages(cand)):
+                if (self._evictable_pages(now, cutoff)
+                        < self._admission_pages(cand)):
                     return
                 victim = self._pick_victim(now, below=cutoff)
                 if victim is None:
@@ -1880,17 +1790,14 @@ class Engine:
             first = self._prefix_place(slot, req, key)
         else:
             pad_len = self._prefill_pad(p_len)
-            if self._paged:
-                # tokens stay at pad_len (page-rounding them would feed
-                # extra pad tokens through mamba mixers); the prefill
-                # stage zero-grows the cache to whole pages instead
-                pages = self.allocator.alloc(self._alloc_pages_for(req))
-                self.page_table.assign(slot, pages)
-                self._slot_pages[slot] = pages
-                req.cache_rows = max(req.cache_rows,
-                                     len(pages) * self._page_size)
-            else:
-                req.cache_rows = self.scfg.max_len
+            # tokens stay at pad_len (page-rounding them would feed extra
+            # pad tokens through mamba mixers); the prefill stage
+            # zero-grows the cache to whole pages instead
+            pages = self.allocator.alloc(self._alloc_pages_for(req))
+            self.page_table.assign(slot, pages)
+            self._slot_pages[slot] = pages
+            req.cache_rows = max(req.cache_rows,
+                                 len(pages) * self._page_size)
             padded = np.zeros((1, pad_len), np.int32)
             padded[0, :p_len] = req.prompt
             self.prefill_tokens += p_len
@@ -2117,8 +2024,7 @@ class Engine:
         self._req_keys.pop(req.id, None)
         if slot is not None:
             self._slot_forced[slot] = []
-        if self._paged and slot is not None \
-                and self._slot_pages[slot] is not None:
+        if slot is not None and self._slot_pages[slot] is not None:
             # recycle: the freed pages may be handed to the very next
             # admission; the departing slot's table row is re-pointed at
             # the trash page so its frozen idempotent decode writes
@@ -2196,8 +2102,7 @@ class Engine:
         self._stat_samples += 1
         self._stat_running += sum(r is not None for r in self._slots)
         self.decode_chunks += 1
-        if self._paged:
-            self._stat_in_use += self.allocator.in_use
+        self._stat_in_use += self.allocator.in_use
         counts = np.asarray(
             [len(r.tokens) if r is not None else 0 for r in self._slots],
             np.int32)
@@ -2295,8 +2200,7 @@ class Engine:
                 del buf[:n]
         self._stat_samples += 1
         self._stat_running += sum(r is not None for r in self._slots)
-        if self._paged:
-            self._stat_in_use += self.allocator.in_use
+        self._stat_in_use += self.allocator.in_use
         counts = np.asarray(
             [len(r.tokens) if r is not None else 0 for r in self._slots],
             np.int32)
@@ -2375,7 +2279,7 @@ class Engine:
                 self._finish(req, slot)
                 self._slots[slot] = None
                 self._active[slot] = False
-            elif self._paged:
+            else:
                 self._spec_rollback(slot)
 
     def start(self, t0: float | None = None) -> None:
@@ -2428,24 +2332,18 @@ class Engine:
             # unsatisfiable request, and overcommit/preemption make the
             # state reachable where it was once provably not.  Fail
             # loudly rather than spin on _admit forever.
-            detail = ""
-            if self._paged:
-                cached = (len(self.prefix_cache.pages)
-                          if self.prefix_cache is not None else 0)
-                detail = (f" ({self.allocator.in_use} pages "
-                          f"still in use — {cached} pinned by "
-                          f"the prefix index — "
-                          f"{self.allocator.available} free of "
-                          f"{self.allocator.capacity} "
-                          f"allocatable)")
-                if self.host_pool is not None:
-                    swapped = sum(
-                        1 for e in self._queue._heap
-                        if e[3].swap_pages is not None)
-                    detail += (f" [host tier: "
-                               f"{self.host_pool.in_use}/"
-                               f"{self.host_pool.capacity} pages held, "
-                               f"{swapped} swapped request(s) queued]")
+            cached = (len(self.prefix_cache.pages)
+                      if self.prefix_cache is not None else 0)
+            detail = (f" ({self.allocator.in_use} pages still in use — "
+                      f"{cached} pinned by the prefix index — "
+                      f"{self.allocator.available} free of "
+                      f"{self.allocator.capacity} allocatable)")
+            if self.host_pool is not None:
+                swapped = sum(1 for e in self._queue._heap
+                              if e[3].swap_pages is not None)
+                detail += (f" [host tier: {self.host_pool.in_use}/"
+                           f"{self.host_pool.capacity} pages held, "
+                           f"{swapped} swapped request(s) queued]")
             raise RuntimeError(
                 f"serve scheduler stalled: {len(self._queue)} "
                 f"arrived request(s) cannot be admitted with "
@@ -2486,10 +2384,7 @@ class Engine:
         legitimate holder (call ``release_prefix_cache`` first when the
         prefix index is on) — anything non-zero is a leak, on the
         device pool *or* the host cold tier (a drained engine has no
-        swapped requests and no cold entries left to hold host pages).
-        0 in dense mode (there is no pool to leak from)."""
-        if not self._paged:
-            return 0
+        swapped requests and no cold entries left to hold host pages)."""
         host = self.host_pool.in_use if self.host_pool is not None else 0
         return self.allocator.in_use + host
 

@@ -78,7 +78,7 @@ class Router:
         for i, eng in enumerate(self.replicas):
             eng._next_id = i * _ID_BLOCK
         self._prefix = bool(scfg.prefix_cache)
-        self._page_size = (self.replicas[0].cfg.page_size
+        self._page_size = (self.replicas[0].scfg.page_size
                            if self._prefix else 0)
         self._queue = _PriorityQueue(scfg.priority_aging_s)
         self._next_gid = 0

@@ -12,9 +12,8 @@ definitions cannot drift apart:
   clock (for staggered workloads that includes arrival gaps — the
   continuous-batching question is how much refill recovers of them);
 * ``cache_kb_per_req`` is the mean per-request KV-cache reservation
-  (dense: the full ``max_len`` slab; paged: allocated pages ×
-  page_size) times the engine's per-token cache bytes — the HBM-
-  footprint axis the paged cache exists to shrink;
+  (allocated pages × page_size) times the engine's per-token cache
+  bytes — the HBM-footprint axis the paged cache exists to shrink;
 * ``priority_mix`` marks that fraction of requests priority 1 (rest 0)
   and splits the latency percentiles per class, so the priority
   scheduler's effect is visible in one run;

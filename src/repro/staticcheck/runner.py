@@ -71,8 +71,8 @@ def build_cell_engine(cell: GridCell):
     cfg = reduced(get_config(ARCH))
     scfg = ServeConfig(
         batch=BATCH, max_len=MAX_LEN, prefill_len=PREFILL_LEN,
-        decode_chunk=DECODE_CHUNK, cache_mode="paged",
-        page_size=PAGE_SIZE, quant_mode=cell.quant_mode,
+        decode_chunk=DECODE_CHUNK, page_size=PAGE_SIZE,
+        quant_mode=cell.quant_mode,
         quant_backend=cell.backend,
         spec_decode=(cell.mode == "spec"), spec_k=SPEC_K,
         prefill_chunk=(WAVE_CHUNK if cell.mode == "wave" else 0),

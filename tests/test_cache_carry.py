@@ -1,13 +1,15 @@
 """The block caches ride the layer scan as a carry updated in place.
 
 Every stage program that decodes (``decode_chunk``, draft, verify, the
-wave prefill) hands the stacked KV pool, dense slab or mamba state to
-``decode_step``; the scanned blocks write each layer's rows straight
-into the stack.  These tests pin that the carry changes no bit: served
-tokens and the caches left behind equal, bit for bit, those of a
-``decode_step`` that scans the block caches as ``xs`` and restacks what
-each layer returns as ``ys`` (the scan before the carry), both scanned
-and unrolled (``unrolled_blocks()``, the costing path).  A scanned and an
+wave prefill) hands the stacked KV pool or mamba state to
+``decode_step``, and a plain ``decode_step`` over one sequence's
+unpaged cache (the reference the serving tests hold the engine to) does
+the same with that cache; the scanned blocks write each layer's rows
+straight into the stack.  These tests pin that the carry changes no
+bit: served tokens and the caches left behind equal, bit for bit, those
+of a ``decode_step`` that scans the block caches as ``xs`` and restacks
+what each layer returns as ``ys`` (the scan before the carry), both
+scanned and unrolled (``unrolled_blocks()``, the costing path).  A scanned and an
 unrolled program are not compared with each other bit for bit: the CPU
 compiler rounds a bf16 intermediate in one and not in the other.
 
@@ -31,11 +33,13 @@ from repro.serve import Engine, ServeConfig
 from repro.serve import engine as engine_mod
 
 # name -> (arch, block repeats, model overrides, serve overrides); three
-# or more blocks, so a layer's pages sit at a non-zero offset of the stack
+# or more blocks, so a layer's pages sit at a non-zero offset of the
+# stack.  ``None`` decodes one sequence at the model level over its
+# unpaged cache instead of serving it.
 CASES = {
     "paged_bf16": ("qwen3-4b", 3, {}, {}),
     "paged_int8": ("qwen3-4b", 3, {"kv_cache_dtype": "int8"}, {}),
-    "dense_slab": ("qwen3-4b", 3, {}, {"cache_mode": "dense"}),
+    "dense_slab": ("qwen3-4b", 3, {}, None),
     "mla": ("deepseek-v3-671b", 2, {}, {}),
     "mamba_hybrid": ("jamba-v0.1-52b", 2, {}, {}),
     "spec_draft_verify": ("yi-6b", 3, {}, {"spec_decode": True,
@@ -53,7 +57,7 @@ def _model(arch, blocks, over):
 
 def _serve(cfg, params, serve_over):
     kw = dict(batch=2, max_len=32, prefill_len=8, decode_chunk=3,
-              cache_mode="paged", page_size=4)
+              page_size=4)
     kw.update(serve_over)
     engine = Engine(cfg, params, ServeConfig(**kw))
     rng = np.random.default_rng(0)
@@ -65,6 +69,22 @@ def _serve(cfg, params, serve_over):
     caches = [np.asarray(a) for a in jax.tree_util.tree_leaves(
         engine._caches)]
     return tokens, caches
+
+
+def _decode_unpaged(cfg, params, step):
+    """Greedy decode of one sequence through ``step`` (a ``decode_step``)
+    over the unpaged cache its prefill grows to 32 rows."""
+    rng = np.random.default_rng(0)
+    prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, 5)), jnp.int32)
+    logits, caches, _ = tr.prefill(params, cfg, prompt, max_len=32)
+    tokens = []
+    for i in range(6):
+        tok = jnp.argmax(logits[:, -1].astype(jnp.float32), -1)
+        tokens.append(int(tok[0]))
+        logits, caches = step(params, cfg, tok[:, None].astype(jnp.int32),
+                              caches, 5 + i)
+    return [tokens], [np.asarray(a)
+                      for a in jax.tree_util.tree_leaves(caches)]
 
 
 def _xs_ys_decode_step(params, cfg, token, caches, index, *,
@@ -131,10 +151,15 @@ def test_carried_cache_bitmatches_xs_ys(case, unrolled, monkeypatch):
     arch, blocks, over, serve_over = CASES[case]
     cfg, params = _model(arch, blocks, over)
     monkeypatch.setattr(tr, "_UNROLL_BLOCKS", unrolled)
-    carried = _serve(cfg, params, serve_over)
+    if serve_over is None:
+        carried = _decode_unpaged(cfg, params, tr.decode_step)
+        want = _decode_unpaged(cfg, params, _xs_ys_decode_step)
+    else:
+        carried = _serve(cfg, params, serve_over)
+        monkeypatch.setattr(engine_mod, "decode_step", _xs_ys_decode_step)
+        want = _serve(cfg, params, serve_over)
     assert all(len(t) == 6 for t in carried[0])
-    monkeypatch.setattr(engine_mod, "decode_step", _xs_ys_decode_step)
-    _assert_same(carried, _serve(cfg, params, serve_over))
+    _assert_same(carried, want)
 
 
 # ---------------------------------------------------------------------------

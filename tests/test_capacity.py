@@ -46,12 +46,11 @@ _COSTS = StageCosts(prefill_s=0.01, decode_chunk_s=0.004)
 # ---------------------------------------------------------------------------
 
 def test_zero_arrival_batch_closed_form():
-    """Two simultaneous requests, dense cache: two serialized prefills
+    """Two simultaneous requests, parity pool: two serialized prefills
     (t=0.01, 0.02), then two batched decode chunks of 4 steps cover the
     remaining 8 tokens each — wall 0.028 s for 18 tokens."""
     shape = WorkloadShape(requests=2, new_tokens=9, **_SHAPE2)
-    knobs = Knobs(batch=2, max_len=16, decode_chunk=4,
-                  cache_mode="dense")
+    knobs = Knobs(batch=2, max_len=16, decode_chunk=4)
     r = predict(knobs, shape, _COSTS)
     assert r["feasible"]
     assert r["tokens"] == 18
@@ -69,8 +68,7 @@ def test_single_stream_decode_closed_form():
     chunks emit the other 16 — wall 0.018 s, TTFT exactly the prefill
     latency."""
     shape = WorkloadShape(requests=1, new_tokens=17, **_SHAPE2)
-    knobs = Knobs(batch=2, max_len=32, decode_chunk=8,
-                  cache_mode="dense")
+    knobs = Knobs(batch=2, max_len=32, decode_chunk=8)
     r = predict(knobs, shape, _COSTS)
     assert r["feasible"]
     assert r["tokens"] == 17
@@ -87,8 +85,7 @@ def test_saturated_pool_serializes_closed_form():
     only after the first frees its pages, so the wall doubles."""
     shape = WorkloadShape(requests=2, new_tokens=9, **_SHAPE2)
     knobs = Knobs(batch=2, max_len=16, decode_chunk=4,
-                  cache_mode="paged", page_size=4, num_pages=4,
-                  alloc_mode="reserve")
+                  page_size=4, num_pages=4, alloc_mode="reserve")
     r = predict(knobs, shape, _COSTS)
     assert r["feasible"]
     assert r["tokens"] == 18
@@ -104,8 +101,7 @@ def test_pool_too_small_raises():
     CapacityError (mirroring Engine.validate), not a silent stall."""
     shape = WorkloadShape(requests=1, new_tokens=9, **_SHAPE2)
     knobs = Knobs(batch=2, max_len=16, decode_chunk=4,
-                  cache_mode="paged", page_size=4, num_pages=2,
-                  alloc_mode="reserve")
+                  page_size=4, num_pages=2, alloc_mode="reserve")
     with pytest.raises(CapacityError, match="pool"):
         predict(knobs, shape, _COSTS)
 
@@ -117,8 +113,7 @@ def test_spec_emission_matches_geometric_model():
     alpha, k = 0.8, 4
     shape = WorkloadShape(requests=2, new_tokens=17, **_SHAPE2)
     knobs = Knobs(batch=2, max_len=32, decode_chunk=8,
-                  cache_mode="paged", page_size=4,
-                  spec_decode=True, spec_k=k)
+                  page_size=4, spec_decode=True, spec_k=k)
     costs = StageCosts(prefill_s=0.01, draft_s=0.002, verify_s=0.004)
     r = predict(knobs, shape, costs, acceptance=alpha)
     assert r["feasible"]
@@ -142,8 +137,8 @@ def test_more_pages_never_lower_tok_s():
     prev = 0.0
     for pages in (9, 13, 17, 25, 33, 65):
         r = predict(Knobs(batch=4, max_len=32, decode_chunk=4,
-                          cache_mode="paged", page_size=4,
-                          num_pages=pages, alloc_mode="incremental"),
+                          page_size=4, num_pages=pages,
+                          alloc_mode="incremental"),
                     shape, costs)
         assert r["feasible"], pages
         assert r["tok_per_s"] >= prev - 1e-9, pages
@@ -159,8 +154,7 @@ def test_larger_decode_chunk_never_worse_throughput():
     for dc in (1, 2, 4, 8, 16):
         costs = StageCosts(prefill_s=0.005,
                            decode_chunk_s=0.001 * dc + 0.0005)
-        r = predict(Knobs(batch=4, max_len=32, decode_chunk=dc,
-                          cache_mode="dense"),
+        r = predict(Knobs(batch=4, max_len=32, decode_chunk=dc),
                     shape, costs)
         assert r["feasible"], dc
         assert r["tok_per_s"] >= prev - 1e-9, dc
@@ -193,11 +187,10 @@ def test_autotune_search_objectives():
                              objective="min-pages", ttft_slo_ms=None,
                              alpha=0.8)
     assert winner is not None
-    assert winner["knobs"].paged
     assert winner["prediction"]["preemptions"] == 0
-    # no admissible paged cell has a smaller pool
+    # no admissible cell has a smaller pool
     for r in results:
-        if r["admissible"] and r["knobs"].paged:
+        if r["admissible"]:
             assert (r["knobs"].resolved_num_pages
                     >= winner["knobs"].resolved_num_pages)
 
@@ -215,8 +208,7 @@ def test_analytic_cache_bytes_match_engine():
     cfg = reduced(get_config("yi-6b"))
     params = model_init(jax.random.PRNGKey(0), cfg)
     engine = Engine(cfg, params, ServeConfig(
-        batch=2, max_len=16, prefill_len=8, cache_mode="paged",
-        page_size=4))
+        batch=2, max_len=16, prefill_len=8, page_size=4))
     assert analytic_cache_token_bytes(cfg) == int(
         engine.cache_token_bytes)
 

@@ -74,7 +74,7 @@ def runs(tmp_path_factory):
     cfg = reduced(get_config("qwen3-4b")).replace(quant_mode="w8a8_nibble")
     params = model_init(jax.random.PRNGKey(0), cfg)
     scfg = ServeConfig(batch=BATCH, max_len=48, prefill_len=PREFILL_LEN,
-                       cache_mode="paged", page_size=8, decode_chunk=4)
+                       page_size=8, decode_chunk=4)
     engine = Engine(cfg, params, scfg)
     plain = _serve(engine, cfg.vocab_size)
     trace_dir = tmp_path_factory.mktemp("engine-trace")

@@ -46,9 +46,8 @@ _CHILD = textwrap.dedent("""
     def run(quant, backend, tp):
         scfg = ServeConfig(batch=2, max_len=24, prefill_len=8,
                            decode_chunk=4, quant_mode=quant,
-                           quant_backend=backend, cache_mode="paged",
-                           page_size=4, alloc_mode="incremental",
-                           num_pages=10, tp=tp)
+                           quant_backend=backend, page_size=4,
+                           alloc_mode="incremental", num_pages=10, tp=tp)
         eng = Engine(cfg, params, scfg)
         ids = [eng.submit(p, 8) for p in prompts]
         done = eng.run()
@@ -100,7 +99,7 @@ _DP_CHILD = textwrap.dedent("""
     cfg = reduced(get_config("qwen3-4b"))
     params = model_init(jax.random.PRNGKey(0), cfg)
     scfg = ServeConfig(batch=2, max_len=24, prefill_len=8, decode_chunk=4,
-                       cache_mode="paged", page_size=4)
+                       page_size=4)
     rng = np.random.default_rng(0)
     prompts = [rng.integers(0, cfg.vocab_size, rng.integers(4, 9))
                for _ in range(8)]

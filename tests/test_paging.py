@@ -1,6 +1,7 @@
 """Paged KV cache: allocator invariants, page-table attention parity
-(paged decode must BIT-match the dense slab), recompile-free recycling,
-admission backpressure, and the priority scheduler."""
+(the paged engine must BIT-match each sequence decoded alone over its
+unpaged cache), recompile-free recycling, admission backpressure, and
+the priority scheduler."""
 
 import jax
 import jax.numpy as jnp
@@ -11,6 +12,7 @@ from repro.configs import get_config, reduced
 from repro.models import model_init
 from repro.serve import Engine, PageAllocator, PageTable, ServeConfig
 from repro.serve.paging import pages_needed
+from test_serve import _solo_greedy
 
 
 def _setup(quant="dense", **cfg_over):
@@ -19,11 +21,11 @@ def _setup(quant="dense", **cfg_over):
     return cfg, params
 
 
-def _run_stream(cfg, params, prompts, n_new, *, cache_mode, **scfg_over):
-    kw = dict(batch=3, max_len=16, prefill_len=8, decode_chunk=3)
+def _run_stream(cfg, params, prompts, n_new, **scfg_over):
+    kw = dict(batch=3, max_len=16, prefill_len=8, decode_chunk=3,
+              page_size=4)
     kw.update(scfg_over)
-    engine = Engine(cfg, params, ServeConfig(**kw, cache_mode=cache_mode,
-                                             page_size=4))
+    engine = Engine(cfg, params, ServeConfig(**kw))
     ids = [engine.submit(p, n_new) for p in prompts]
     done = engine.run()
     return engine, [done[i].tokens for i in ids]
@@ -194,56 +196,54 @@ def test_truncate_preserves_refcounted_shared_pages():
 
 
 # ---------------------------------------------------------------------------
-# Paged decode parity: BIT-identical to the dense slab
+# Paged decode parity: BIT-identical to each sequence decoded alone
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("quant,backend", [
-    ("dense", "xla"), ("dense", "pallas"),
-    ("w8a8_nibble", "xla"), ("w8a8_nibble", "pallas"),
-])
-def test_paged_matches_dense_bitwise(quant, backend):
-    """Same request stream through a dense-slab engine and a paged
-    engine: after the page gather the attention math is shape- and
-    value-identical, so greedy decode must BIT-match."""
-    cfg, params = _setup(quant, quant_backend=backend)
+# id -> (arch, model overrides, slots).  w8a8 cases serve one slot: the
+# per-tensor activation scale depends on which rows share a dispatch.
+# So do MoE cases: routed capacity drops depend on the batch.  A
+# one-slot case also prefills at exact length, as the reference does:
+# pad rows share the prefill dispatch the same way.
+_PARITY = {
+    "yi-dense-xla": ("yi-6b", {}, 3),
+    "yi-dense-pallas": ("yi-6b", {"quant_backend": "pallas"}, 3),
+    "yi-w8a8-xla": ("yi-6b", {"quant_mode": "w8a8_nibble"}, 1),
+    "yi-w8a8-pallas": ("yi-6b", {"quant_mode": "w8a8_nibble",
+                                 "quant_backend": "pallas"}, 1),
+    "yi-int8-kv": ("yi-6b", {"kv_cache_dtype": "int8"}, 2),
+    "deepseek-mla": ("deepseek-v3-671b", {}, 1),
+    "jamba-hybrid": ("jamba-v0.1-52b", {}, 1),
+    "qwen3-dense": ("qwen3-4b", {}, 3),
+    "qwen3-w8a8": ("qwen3-4b", {"quant_mode": "w8a8_nibble"}, 1),
+    "gemma3-dense": ("gemma3-1b", {}, 3),
+    "gemma3-w8a8": ("gemma3-1b", {"quant_mode": "w8a8_nibble"}, 1),
+    "llama4-moe-dense": ("llama4-maverick-400b-a17b", {}, 1),
+    "llama4-moe-w8a8": ("llama4-maverick-400b-a17b",
+                        {"quant_mode": "w8a8_nibble"}, 1),
+    "mamba2-dense": ("mamba2-780m", {}, 3),
+    "mamba2-w8a8": ("mamba2-780m", {"quant_mode": "w8a8_nibble"}, 1),
+}
+
+
+@pytest.mark.parametrize("case", list(_PARITY))
+def test_paged_matches_solo_reference(case):
+    """Requests served through the paged engine (pool scatter, gather
+    through the page table, slot refill) emit, bit for bit, the greedy
+    stream of each sequence decoded alone at scalar positions over its
+    own unpaged cache — across backends, quant modes, the int8 KV cache,
+    MLA latent pools, mamba state (exact-length prefill, per-slot SSM
+    state next to the pools), local attention and MoE."""
+    arch, over, slots = _PARITY[case]
+    cfg = reduced(get_config(arch)).replace(**over)
+    params = model_init(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     prompts = [jnp.asarray(rng.integers(0, cfg.vocab_size, p), jnp.int32)
                for p in (3, 5, 7)]
-    _, want = _run_stream(cfg, params, prompts, 4, cache_mode="dense")
-    engine, got = _run_stream(cfg, params, prompts, 4, cache_mode="paged")
-    assert got == want, (quant, backend, got, want)
+    engine, got = _run_stream(cfg, params, prompts, 4, batch=slots,
+                              prefill_len=8 if slots > 1 else 0)
+    want = [_solo_greedy(params, cfg, p, 4, 16) for p in prompts]
+    assert got == want, (case, got, want)
     assert engine.allocator.in_use == 0    # every page returned
-
-
-def test_paged_int8_kv_matches_dense():
-    """The int8 KV cache quantizes identically through pool scatter and
-    slab scatter — still bit-exact between the two layouts."""
-    cfg, params = _setup(kv_cache_dtype="int8")
-    rng = np.random.default_rng(1)
-    prompts = [jnp.asarray(rng.integers(0, cfg.vocab_size, p), jnp.int32)
-               for p in (4, 6)]
-    _, want = _run_stream(cfg, params, prompts, 4, cache_mode="dense",
-                          batch=2)
-    _, got = _run_stream(cfg, params, prompts, 4, cache_mode="paged",
-                         batch=2)
-    assert got == want
-
-
-def test_paged_mla_and_hybrid_match_dense():
-    """MLA latent pools (deepseek) and the mamba/attn hybrid (jamba,
-    exact-length prefill + per-slot SSM state next to paged attention
-    layers) both bit-match their dense duals."""
-    rng = np.random.default_rng(2)
-    for arch in ("deepseek-v3-671b", "jamba-v0.1-52b"):
-        cfg = reduced(get_config(arch))
-        params = model_init(jax.random.PRNGKey(0), cfg)
-        prompts = [jnp.asarray(rng.integers(0, cfg.vocab_size, p),
-                               jnp.int32) for p in (3, 6)]
-        _, want = _run_stream(cfg, params, prompts, 4, cache_mode="dense",
-                              batch=2)
-        _, got = _run_stream(cfg, params, prompts, 4, cache_mode="paged",
-                             batch=2)
-        assert got == want, arch
 
 
 # ---------------------------------------------------------------------------
@@ -254,25 +254,26 @@ def test_paged_refill_no_recompile_no_leak():
     """More requests than slots with mixed lengths/budgets: slots refill
     onto RECYCLED pages (the pool is sized so late requests must reuse
     early requests' pages) with both compiled programs intact, every
-    page returned, and output equal to the dense engine's."""
+    page returned, and output equal to a parity pool's, where no page
+    is ever recycled mid-run."""
     cfg, params = _setup()
     rng = np.random.default_rng(1)
     spec = [(4, 6), (8, 3), (5, 7), (6, 1), (3, 5)]
     prompts = [jnp.asarray(rng.integers(0, cfg.vocab_size, p), jnp.int32)
                for p, _ in spec]
 
-    def drive(cache_mode, num_pages=None):
+    def drive(num_pages):
         engine = Engine(cfg, params, ServeConfig(
             batch=2, max_len=24, prefill_len=8, decode_chunk=4,
-            cache_mode=cache_mode, page_size=4, num_pages=num_pages))
+            page_size=4, num_pages=num_pages))
         ids = [engine.submit(p, n) for p, (_, n) in zip(prompts, spec)]
         done = engine.run()
         return engine, [done[i].tokens for i in ids]
 
-    _, want = drive("dense")
+    _, want = drive(num_pages=0)          # parity pool
     # 13 pages = trash + two concurrent worst-case requests (2 × 6);
     # five requests therefore cannot run without recycling
-    engine, got = drive("paged", num_pages=13)
+    engine, got = drive(num_pages=13)
     assert got == want
     assert engine.compile_counts == {"prefill": 1, "decode_chunk": 1}
     assert engine.allocator.in_use == 0
@@ -291,13 +292,13 @@ def test_paged_admission_backpressure_serializes():
     def drive(num_pages):
         engine = Engine(cfg, params, ServeConfig(
             batch=3, max_len=16, prefill_len=8, decode_chunk=3,
-            cache_mode="paged", page_size=4, num_pages=num_pages))
+            page_size=4, num_pages=num_pages))
         ids = [engine.submit(p, 4) for p in prompts]
         done = engine.run()
         return engine, [done[i].tokens for i in ids]
 
     # pages_for(5 prompt + 4 new) = ceil(8/4) = 2 → capacity 2 fits one
-    _, want = drive(num_pages=None)        # roomy auto pool
+    _, want = drive(num_pages=0)           # roomy parity pool
     engine, got = drive(num_pages=3)
     assert got == want
     assert engine.allocator.in_use == 0
@@ -307,48 +308,48 @@ def test_paged_request_too_big_for_pool_raises():
     cfg, params = _setup()
     engine = Engine(cfg, params, ServeConfig(
         batch=1, max_len=16, prefill_len=8, decode_chunk=2,
-        cache_mode="paged", page_size=4, num_pages=2))
+        page_size=4, num_pages=2))
     with pytest.raises(ValueError, match="pool"):
         engine.submit(jnp.asarray([1, 2, 3, 4, 5], jnp.int32), 8)
 
 
 def test_paged_cache_rows_scale_with_live_tokens():
     """The HBM claim: a short request reserves only its pages (prompt +
-    decode-written rows rounded to page_size), not the max_len slab."""
+    decode-written rows rounded to page_size), not a max_len slab."""
+    from repro.models import init_caches
+    from repro.models.transformer import _SEQ_CACHE_KEYS
     cfg, params = _setup()
     engine = Engine(cfg, params, ServeConfig(
-        batch=1, max_len=32, prefill_len=8, decode_chunk=2,
-        cache_mode="paged", page_size=4))
+        batch=1, max_len=32, prefill_len=8, decode_chunk=2, page_size=4))
     rid = engine.submit(jnp.asarray([1, 2, 3], jnp.int32), 2)
     done = engine.run()
     # 3 prompt rows + 1 decode write = 4 rows → exactly 1 page
     assert done[rid].cache_rows == 4
-    dense = Engine(cfg, params, ServeConfig(batch=1, max_len=32,
-                                            prefill_len=8, decode_chunk=2))
-    rid = dense.submit(jnp.asarray([1, 2, 3], jnp.int32), 2)
-    assert dense.run()[rid].cache_rows == 32
-    # same per-token bytes either way: the layout moves rows, not widths
-    assert engine.cache_token_bytes == dense.cache_token_bytes
+    assert done[rid].cache_rows < engine.scfg.max_len
+    # same per-token bytes as the unpaged (1, max_len) cache: the layout
+    # moves rows, not widths
+    slab = jax.eval_shape(lambda: init_caches(cfg, 1, 32))
+    slab_bytes = sum(
+        leaf.size * leaf.dtype.itemsize for path, leaf in
+        jax.tree_util.tree_flatten_with_path(slab)[0]
+        if path[-1].key in _SEQ_CACHE_KEYS)
+    assert engine.cache_token_bytes == slab_bytes // 32
 
 
 def test_paged_vs_dense_hbm_per_request():
-    """Workload-level accounting: cache_kb_per_req in paged mode sits
-    measurably below the dense max_len slab on short requests."""
+    """Workload-level accounting: cache_kb_per_req sits measurably below
+    a max_len slab's per-request share on short requests."""
     from repro.serve import run_timed_workload
     cfg, params = _setup()
-
-    def measure(cache_mode):
-        engine = Engine(cfg, params, ServeConfig(
-            batch=2, max_len=32, prefill_len=8, decode_chunk=4,
-            cache_mode=cache_mode, page_size=4))
-        return run_timed_workload(engine, cfg.vocab_size, requests=4,
-                                  prompt_budget=8, new_tokens=4)
-
-    dense = measure("dense")
-    paged = measure("paged")
-    # dense reserves 32 rows/request; paged at most ceil(11/4)=3 pages
-    # = 12 rows
-    assert paged["cache_kb_per_req"] < dense["cache_kb_per_req"] / 2
+    scfg = ServeConfig(batch=2, max_len=32, prefill_len=8, decode_chunk=4,
+                       page_size=4)
+    engine = Engine(cfg, params, scfg)
+    paged = run_timed_workload(engine, cfg.vocab_size, requests=4,
+                               prompt_budget=8, new_tokens=4)
+    # a batch × max_len slab holds 32 rows per request; the pool books
+    # at most ceil(11/4)=3 pages = 12 rows
+    slab_kb_per_req = scfg.max_len * engine.cache_token_bytes / 1024
+    assert paged["cache_kb_per_req"] < slab_kb_per_req / 2
 
 
 # ---------------------------------------------------------------------------
@@ -390,10 +391,8 @@ def test_paged_flash_engine_end_to_end():
     prompts = [jnp.asarray(rng.integers(0, cfg.vocab_size, p), jnp.int32)
                for p in (3, 6)]
     ref_cfg, _ = _setup()                  # chunked reference
-    _, want = _run_stream(ref_cfg, params, prompts, 4, cache_mode="paged",
-                          batch=2)
-    _, got = _run_stream(cfg, params, prompts, 4, cache_mode="paged",
-                         batch=2)
+    _, want = _run_stream(ref_cfg, params, prompts, 4, batch=2)
+    _, got = _run_stream(cfg, params, prompts, 4, batch=2)
     assert got == want
 
 
@@ -487,18 +486,12 @@ def test_workload_validates_inputs():
 # ---------------------------------------------------------------------------
 
 def test_paged_engine_validates_page_geometry():
+    from repro.launch.serve import build_parser
     cfg, params = _setup()
     with pytest.raises(ValueError, match="multiple of page_size"):
-        Engine(cfg, params, ServeConfig(batch=1, max_len=18,
-                                        cache_mode="paged", page_size=4))
-    with pytest.raises(ValueError, match="cache_mode"):
-        Engine(cfg, params, ServeConfig(batch=1, max_len=16,
-                                        cache_mode="sparse"))
-
-
-def test_make_serve_step_rejects_paged():
-    from repro.serve import make_serve_step
-    cfg, _ = _setup()
-    with pytest.raises(ValueError, match="paged"):
-        make_serve_step(cfg, ServeConfig(batch=1, max_len=16,
-                                         cache_mode="paged", page_size=4))
+        Engine(cfg, params, ServeConfig(batch=1, max_len=18, page_size=4))
+    # the pool is the one layout: the launcher refuses any other
+    for mode in ("sparse", "dense"):
+        with pytest.raises(SystemExit):
+            build_parser().parse_args(["--cache-mode", mode])
+    assert build_parser().parse_args([]).cache_mode == "paged"

@@ -25,7 +25,7 @@ def model():
 
 def _scfg(**over):
     kw = dict(batch=3, max_len=16, prefill_len=8, decode_chunk=3,
-              cache_mode="paged", page_size=4)
+              page_size=4)
     kw.update(over)
     return ServeConfig(**kw)
 
@@ -48,15 +48,14 @@ def test_overcommitted_pool_drains_bitmatch(model):
     worst-case page counts (4 requests x 4 pages worst case, capacity
     6).  Incremental allocation + preemption must drain every request,
     return every page, keep both compiled programs single, and produce
-    the exact token streams of an uncontended dense engine."""
+    the exact token streams of an uncontended engine on a parity pool."""
     cfg, params = model
     rng = np.random.default_rng(0)
     budgets = [8, 8, 8, 8]
     prompts = [jnp.asarray(rng.integers(0, cfg.vocab_size, p), jnp.int32)
                for p in (4, 6, 5, 7)]
 
-    _, want = _drive(cfg, params, prompts, budgets,
-                     _scfg(cache_mode="dense", page_size=None))
+    _, want = _drive(cfg, params, prompts, budgets, _scfg())
     engine, got = _drive(cfg, params, prompts, budgets,
                          _scfg(alloc_mode="incremental", num_pages=7))
     assert [r.tokens for r in got] == [r.tokens for r in want]
@@ -211,28 +210,6 @@ def test_equal_priority_never_preempts(model):
     assert engine.allocator.in_use == 0
 
 
-def test_preemption_in_dense_mode(model):
-    """Slot preemption does not depend on paging: the dense engine
-    evicts and resumes bit-identically too (no allocator involved)."""
-    cfg, params = model
-    rng = np.random.default_rng(3)
-    lo_p = jnp.asarray(rng.integers(0, cfg.vocab_size, 5), jnp.int32)
-    hi_p = jnp.asarray(rng.integers(0, cfg.vocab_size, 3), jnp.int32)
-    scfg = _scfg(batch=1, decode_chunk=2, cache_mode="dense",
-                 page_size=None)
-    engine = Engine(cfg, params, scfg)
-    lo = engine.submit(lo_p, 6)
-    engine._t0 = time.perf_counter()
-    engine._admit(0.0)
-    engine._run_chunk(0.0)
-    hi = engine.submit(hi_p, 4, priority=9)
-    engine._admit(0.0)
-    assert engine._slots[0].id == hi and engine.preemptions == 1
-    done = engine.run()
-    _, (ref,) = _drive(cfg, params, [lo_p], [6], scfg)
-    assert done[lo].tokens == ref.tokens
-
-
 def test_preempted_mid_replay_carries_full_stream(model):
     """Evicting a slot that is itself still replaying must splice the
     unreplayed tail back onto the requeued request — nothing of the
@@ -286,8 +263,7 @@ def test_counting_jit_tracks_signatures():
 
 def test_submit_truncation_is_explicit(model):
     cfg, params = model
-    engine = Engine(cfg, params, _scfg(batch=1, cache_mode="dense",
-                                       page_size=None))
+    engine = Engine(cfg, params, _scfg(batch=1))
     rid = engine.submit(jnp.asarray([1, 2, 3, 4, 5], jnp.int32), 100)
     done = engine.run()
     assert done[rid].truncated                # not silently clamped
@@ -322,14 +298,10 @@ def test_scheduler_stall_raises_not_spins(model):
         engine.run()
 
 
-def test_incremental_requires_paged(model):
+def test_alloc_mode_validated(model):
     cfg, params = model
-    with pytest.raises(ValueError, match="incremental"):
-        Engine(cfg, params, ServeConfig(batch=1, max_len=16,
-                                        alloc_mode="incremental"))
     with pytest.raises(ValueError, match="alloc_mode"):
-        Engine(cfg, params, ServeConfig(batch=1, max_len=16,
-                                        alloc_mode="lazy"))
+        Engine(cfg, params, _scfg(alloc_mode="lazy"))
 
 
 def test_workload_reports_scheduler_stats(model):

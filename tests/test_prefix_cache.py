@@ -29,7 +29,7 @@ def model():
 
 def _scfg(**over):
     kw = dict(batch=2, max_len=16, prefill_len=8, decode_chunk=3,
-              cache_mode="paged", page_size=4)
+              page_size=4)
     kw.update(over)
     return ServeConfig(**kw)
 
@@ -282,8 +282,7 @@ def test_evict_while_shared_survivor_keeps_pages(model):
     # pool dry and preempt a sharing runner mid-stream
     over = dict(batch=3, max_len=16, num_pages=10,
                 alloc_mode="incremental")
-    _, want = _drive(cfg, params, prompts, budgets,
-                     _scfg(cache_mode="dense", page_size=None, batch=3))
+    _, want = _drive(cfg, params, prompts, budgets, _scfg(batch=3))
     engine, got = _drive(cfg, params, prompts, budgets,
                          _scfg(prefix_cache=True, **over))
     assert engine.preemptions > 0           # the scenario actually fired
@@ -406,13 +405,6 @@ def test_exact_length_prefill_shared_prefix_bitmatch(model):
     assert engine.stats["prefix_hits"] == 1
     engine.release_prefix_cache()
     assert engine.allocator.in_use == 0
-
-
-def test_prefix_cache_requires_paged(model):
-    cfg, params = model
-    with pytest.raises(ValueError, match="requires"):
-        Engine(cfg, params, _scfg(cache_mode="dense", page_size=None,
-                                  prefix_cache=True))
 
 
 def test_prefix_cache_rejects_mamba_and_int8_kv():

@@ -30,7 +30,7 @@ def model():
 
 def _scfg(**over):
     kw = dict(batch=2, max_len=16, prefill_len=8, decode_chunk=3,
-              cache_mode="paged", page_size=4, alloc_mode="incremental")
+              page_size=4, alloc_mode="incremental")
     kw.update(over)
     return ServeConfig(**kw)
 

@@ -8,7 +8,7 @@ import pytest
 
 from repro.configs import get_config, reduced
 from repro.models import model_init
-from repro.serve import Engine, ServeConfig, make_serve_step
+from repro.serve import Engine, ServeConfig
 
 
 def _setup(quant="dense"):
@@ -18,15 +18,20 @@ def _setup(quant="dense"):
 
 
 def _solo_greedy(params, cfg, prompt, n_new, max_len):
-    """Reference: one sequence decoded alone at scalar positions."""
+    """Reference: one sequence decoded alone at scalar positions over its
+    own unpaged cache.  Both model calls are jitted, as the engine's
+    stages are: an op-by-op run rounds the quantized modes' activation
+    scales differently from a compiled program."""
     from repro.models import decode_step, prefill
-    logits, caches, _ = prefill(params, cfg, prompt[None], max_len=max_len)
+    run_prefill = jax.jit(lambda p, t: prefill(p, cfg, t, max_len=max_len))
+    run_decode = jax.jit(lambda p, t, c, i: decode_step(p, cfg, t, c, i))
+    logits, caches, _ = run_prefill(params, prompt[None])
     tok = jnp.argmax(logits[:, -1].astype(jnp.float32), -1)[:, None] \
         .astype(jnp.int32)
     out = [int(tok[0, 0])]
     for i in range(n_new - 1):
-        lg, caches = decode_step(params, cfg, tok, caches,
-                                 prompt.shape[0] + i)
+        lg, caches = run_decode(params, tok, caches,
+                                jnp.int32(prompt.shape[0] + i))
         tok = jnp.argmax(lg[:, -1].astype(jnp.float32), -1)[:, None] \
             .astype(jnp.int32)
         out.append(int(tok[0, 0]))
@@ -52,7 +57,8 @@ def test_greedy_matches_manual_argmax_rollout():
     from repro.models import decode_step, prefill
     prompts = jax.random.randint(jax.random.PRNGKey(2), (1, 8), 0,
                                  cfg.vocab_size)
-    engine = Engine(cfg, params, ServeConfig(batch=1, max_len=24))
+    engine = Engine(cfg, params, ServeConfig(batch=1, max_len=24,
+                                             page_size=8))
     out = engine.generate(prompts, n_new=4)
 
     logits, caches, _ = prefill(params, cfg, prompts, max_len=24)
@@ -67,48 +73,20 @@ def test_greedy_matches_manual_argmax_rollout():
     assert out[0, 8:].tolist() == manual
 
 
-def test_serve_step_jits_once_for_all_positions():
-    from repro.serve.engine import _CountingJit
-    cfg, params = _setup()
-    scfg = ServeConfig(batch=2, max_len=16)
-    step = _CountingJit(make_serve_step(cfg, scfg))
-    from repro.models import init_caches
-    caches = init_caches(cfg, 2, 16)
-    tok = jnp.zeros((2, 1), jnp.int32)
-    rng = jax.random.PRNGKey(0)
-    # different trace-time-identical positions: single compilation
-    tok, caches = step(params, caches, tok, 3, rng)
-    tok, caches = step(params, caches, tok, 4, rng)
-    assert step.compile_count == 1
-    # cross-check against the real jit cache when the (private,
-    # version-dependent) probe exists — skip silently when it moved
-    probe = getattr(step._fn, "_cache_size", None)
-    if probe is not None:
-        assert probe() == 1
-
-
 def test_temperature_sampling_varies():
     cfg, params = _setup()
     scfg = ServeConfig(batch=1, max_len=16, temperature=5.0)
-    step = jax.jit(make_serve_step(cfg, scfg))
-    from repro.models import init_caches
-    caches = init_caches(cfg, 1, 16)
-    tok = jnp.zeros((1, 1), jnp.int32)
-    outs = set()
-    for s in range(8):
-        t, _ = step(params, caches, tok, 2, jax.random.PRNGKey(s))
-        outs.add(int(t[0, 0]))
-    assert len(outs) > 1     # high temperature: not deterministic argmax
-
-    # the FIRST post-prefill token must go through the same path — the
-    # seed engine hardcoded argmax for it regardless of temperature
     engine = Engine(cfg, params, scfg)
     prompts = jax.random.randint(jax.random.PRNGKey(1), (1, 6), 0,
                                  cfg.vocab_size)
-    firsts = {int(engine.generate(prompts, 2,
-                                  rng=jax.random.PRNGKey(s))[0, 6])
-              for s in range(8)}
-    assert len(firsts) > 1
+    outs = [engine.generate(prompts, 2, rng=jax.random.PRNGKey(s))[0, 6:]
+            for s in range(8)]
+    # high temperature: the decode chunk's draw is not a deterministic
+    # argmax
+    assert len({int(o[1]) for o in outs}) > 1
+    # the FIRST post-prefill token must go through the same path — the
+    # seed engine hardcoded argmax for it regardless of temperature
+    assert len({int(o[0]) for o in outs}) > 1
 
 
 def test_int8_kv_cache_decode_accuracy():
@@ -205,7 +183,8 @@ def test_slot_refill_without_recompile():
     too — a refilled slot starts mid-stream next to older sequences."""
     cfg, params = _setup()
     engine = Engine(cfg, params, ServeConfig(batch=2, max_len=24,
-                                             prefill_len=8, decode_chunk=4))
+                                             prefill_len=8, decode_chunk=4,
+                                             page_size=8))
     rng = np.random.default_rng(1)
     spec = [(4, 6), (8, 3), (5, 7), (6, 1), (3, 5)]
     prompts = [jnp.asarray(rng.integers(0, cfg.vocab_size, p), jnp.int32)

@@ -126,9 +126,9 @@ def test_paged_kv_pool_shards_kv_heads_when_divisible():
     from types import SimpleNamespace
 
     from repro.distributed.sharding import cache_specs
-    cfg = SimpleNamespace(cache_mode="paged", n_kv_heads=2)
+    cfg = SimpleNamespace(n_kv_heads=2)
     caches = {"k": FakeLeaf(10, 4, 2, 16), "v": FakeLeaf(10, 4, 2, 16)}
-    specs = cache_specs(cfg, caches, FakeMesh(), batch=2)
+    specs = cache_specs(cfg, caches, FakeMesh(), batch=2, paged=True)
     assert specs["k"] == P(None, None, "model", None)
     assert specs["v"] == P(None, None, "model", None)
 
@@ -141,12 +141,12 @@ def test_paged_kv_pool_falls_back_to_page_sequence_axis():
     from types import SimpleNamespace
 
     from repro.distributed.sharding import cache_specs
-    cfg = SimpleNamespace(cache_mode="paged", n_kv_heads=3)
+    cfg = SimpleNamespace(n_kv_heads=3)
     specs = cache_specs(cfg, {"k": FakeLeaf(10, 4, 3, 16)}, FakeMesh(),
-                        batch=2)
+                        batch=2, paged=True)
     assert specs["k"] == P(None, "model", None, None)
     specs = cache_specs(cfg, {"k": FakeLeaf(10, 5, 3, 16)}, FakeMesh(),
-                        batch=2)
+                        batch=2, paged=True)
     assert specs["k"] == P(None, None, None, None)
 
 
@@ -156,26 +156,26 @@ def test_paged_scale_pools_follow_the_kv_rule():
     from types import SimpleNamespace
 
     from repro.distributed.sharding import cache_specs
-    cfg = SimpleNamespace(cache_mode="paged", n_kv_heads=2)
+    cfg = SimpleNamespace(n_kv_heads=2)
     specs = cache_specs(cfg, {"k_scale": FakeLeaf(10, 4, 2, 1)},
-                        FakeMesh(), batch=2)
+                        FakeMesh(), batch=2, paged=True)
     assert specs["k_scale"] == P(None, None, "model", None)
 
 
 def test_paged_mla_and_stacked_and_mamba_rules():
     """MLA latent pools (num_pages, page_size, rank) have no head axis
     — the in-page sequence axis shards; a stacked (blocks-leading)
-    pool gets a leading None; mamba state keeps its per-slot dense
-    rule even in paged mode."""
+    pool gets a leading None; mamba state keeps its per-slot rule next
+    to the pools."""
     from types import SimpleNamespace
 
     from repro.distributed.sharding import cache_specs
-    cfg = SimpleNamespace(cache_mode="paged", n_kv_heads=2)
+    cfg = SimpleNamespace(n_kv_heads=2)
     specs = cache_specs(
         cfg, {"c_kv": FakeLeaf(10, 4, 8),
               "blocks": {"k": FakeLeaf(3, 10, 4, 2, 16)},
               "conv": FakeLeaf(4, 3, 8)},
-        FakeMesh(), batch=2)
+        FakeMesh(), batch=2, paged=True)
     assert specs["c_kv"] == P(None, "model", None)
     assert specs["blocks"]["k"] == P(None, None, None, "model", None)
     assert specs["conv"] == P(("data",), None, "model")

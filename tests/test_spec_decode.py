@@ -39,7 +39,7 @@ def model():
 
 def _scfg(**over):
     kw = dict(batch=2, max_len=16, prefill_len=8, decode_chunk=3,
-              cache_mode="paged", page_size=4)
+              page_size=4)
     kw.update(over)
     return ServeConfig(**kw)
 
@@ -92,20 +92,6 @@ def test_spec_greedy_bitmatches_dense_engine(model, quant, backend, ):
     # no replay happened, so every token except each request's
     # prefill-emitted first one went through a round
     assert engine.spec_tokens == sum(len(r.tokens) - 1 for r in got)
-
-
-def test_spec_dense_cache_mode_bitmatches(model):
-    """Spec decode is cache-layout-agnostic: the dense slab works too
-    (rollback is simply a no-op — junk rows are overwritten in place)."""
-    cfg, params = model
-    prompts = _prompts(cfg, 2, seed=3)
-    _, want = _drive(cfg, params, prompts, [5, 5],
-                     _scfg(cache_mode="dense", page_size=None))
-    engine, got = _drive(cfg, params, prompts, [5, 5],
-                         _scfg(cache_mode="dense", page_size=None,
-                               spec_decode=True, spec_k=4,
-                               spec_quant_mode="w8a8_nibble"))
-    assert [r.tokens for r in got] == [r.tokens for r in want]
 
 
 def test_spec_temperature_drains_and_accounts(model):

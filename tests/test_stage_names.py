@@ -39,8 +39,8 @@ def _lowered(model, mode):
     signature it recorded: ``{stage: lowered}``."""
     cfg, params = model
     engine = Engine(cfg, params, ServeConfig(
-        batch=2, max_len=32, prefill_len=8, cache_mode="paged",
-        page_size=8, decode_chunk=2, **MODES[mode]))
+        batch=2, max_len=32, prefill_len=8, page_size=8,
+        decode_chunk=2, **MODES[mode]))
     rng = np.random.default_rng(0)
     for n in (3, 5):
         engine.submit(rng.integers(0, cfg.vocab_size, n), 4)

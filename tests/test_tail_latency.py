@@ -29,7 +29,7 @@ def model():
 
 def _scfg(**over):
     kw = dict(batch=3, max_len=16, prefill_len=8, decode_chunk=3,
-              cache_mode="paged", page_size=4)
+              page_size=4)
     kw.update(over)
     return ServeConfig(**kw)
 
@@ -225,15 +225,6 @@ def test_chunked_plus_grouped_bitmatch(model):
     assert _leaks(engine) == 0
 
 
-def test_wave_requires_paged_cache(model):
-    cfg, params = model
-    with pytest.raises(ValueError, match="require"):
-        Engine(cfg, params, _scfg(cache_mode="dense", page_size=None,
-                                  prefill_chunk=4))
-    with pytest.raises(ValueError, match="prefill_len"):
-        Engine(cfg, params, _scfg(admit_group=2, prefill_len=0))
-
-
 # ---------------------------------------------------------------------------
 # Host-tier swap: O(pages) resume, bit-match, zero leaks (both pools)
 # ---------------------------------------------------------------------------
@@ -246,7 +237,7 @@ def test_swap_roundtrip_bitmatch(quant, backend):
     """The acceptance scenario: an overcommitted pool forces evictions
     mid-stream; with swap_mode="host" every resume restores KV rows by
     page copy (swap_in > 0, replayed decode steps saved) and the greedy
-    streams still equal an uncontended dense-slab run's — across the
+    streams still equal an uncontended parity-pool run's — across the
     quant x backend grid, with zero pages leaked on the device AND the
     host pool."""
     cfg = reduced(get_config("yi-6b")).replace(quant_mode=quant,
@@ -255,8 +246,7 @@ def test_swap_roundtrip_bitmatch(quant, backend):
     prompts = _prompts(cfg.vocab_size, (4, 6, 5, 7), seed=4)
     budgets = [8] * 4
 
-    _, want = _drive(cfg, params, prompts, budgets,
-                     _scfg(cache_mode="dense", page_size=None))
+    _, want = _drive(cfg, params, prompts, budgets, _scfg())
     engine, got = _drive(cfg, params, prompts, budgets,
                          _scfg(alloc_mode="incremental", num_pages=7,
                                swap_mode="host"))
@@ -415,11 +405,10 @@ def test_cold_tier_composes_with_chunked_prefill(model):
 # Config validation
 # ---------------------------------------------------------------------------
 
-def test_swap_requires_paged(model):
+def test_tail_knobs_validated(model):
     cfg, params = model
-    with pytest.raises(ValueError, match="swap_mode='host' requires"):
-        Engine(cfg, params, _scfg(cache_mode="dense", page_size=None,
-                                  swap_mode="host"))
+    with pytest.raises(ValueError, match="prefill_len"):
+        Engine(cfg, params, _scfg(admit_group=2, prefill_len=0))
     with pytest.raises(ValueError, match="swap_mode must be"):
         Engine(cfg, params, _scfg(swap_mode="disk"))
     with pytest.raises(ValueError, match="prefill_chunk must be"):

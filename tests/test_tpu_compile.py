@@ -200,7 +200,7 @@ def test_quant_matmul_compiles_tensor_parallel(topo):
 
 # the chat cell's serving settings: 16 slots over 1,537 pages of 16 rows
 _CELL = dict(batch=16, max_len=2560, prefill_len=2048, decode_chunk=8,
-             cache_mode="paged", page_size=16, num_pages=1537,
+             page_size=16, num_pages=1537,
              quant_mode="w8a8_nibble", quant_backend="pallas")
 
 
@@ -232,10 +232,10 @@ def cell_programs(topo):
     b, c = _CELL["batch"], _CELL["decode_chunk"]
     max_pages = _CELL["max_len"] // _CELL["page_size"]
     out = {}
-    init = engine_mod.init_caches
+    init = engine_mod.init_paged_caches
     with pytest.MonkeyPatch.context() as mp:
         mp.setattr(ops, "_on_tpu", lambda: True)   # lower Pallas to Mosaic
-        mp.setattr(engine_mod, "init_caches",
+        mp.setattr(engine_mod, "init_paged_caches",
                    lambda *a: jax.eval_shape(lambda: init(*a)))
         for layers in (4, 8):
             cfg = get_config("qwen3-4b").replace(n_layers=layers)
